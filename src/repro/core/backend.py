@@ -177,9 +177,12 @@ def _load_scenarios() -> None:
     global _loaded
     if _loaded:
         return
-    _loaded = True
     for mod in _SCENARIO_MODULES:
         importlib.import_module(mod)
+    # Only once every module imported: a failed import must raise again on
+    # the next call, not leave a half-filled registry that reports
+    # "unknown scenario kind".
+    _loaded = True
 
 
 def scenario_kinds() -> List[str]:

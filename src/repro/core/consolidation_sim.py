@@ -72,7 +72,7 @@ class VecConsolidationManager(ConsolidationManager):
     ARCHITECTURE.md): per-entity attributes live as padded device arrays
     (traces ``[V, K]``, capacities ``[V]``/``[H]``), the per-step sweep is
     one fused vector pass instead of per-object traversals, and the whole
-    path runs under ``jax.experimental.enable_x64`` so every derived float
+    path runs under :func:`repro.core.vec_engine.x64` so every derived float
     is the same IEEE double the OO managers compute — selection/placement
     decisions reuse the scalar routines and match the OO managers exactly
     (asserted by tests and the Table-2 benchmark).
@@ -84,10 +84,11 @@ class VecConsolidationManager(ConsolidationManager):
 
     def __init__(self, *a, **kw):
         super().__init__(*a, **kw)
-        import jax
         import jax.numpy as jnp
-        self._jax = jax
-        with jax.experimental.enable_x64():
+
+        from .vec_engine import x64
+        self._x64 = x64
+        with x64():
             self._traces = jnp.asarray(
                 np.stack([np.asarray(vm.trace, dtype=np.float64)
                           for vm in self.vms]), jnp.float64)     # [V, K]
@@ -107,7 +108,7 @@ class VecConsolidationManager(ConsolidationManager):
         calls within one interval reuse a single device sweep + sync."""
         k = min(int(t / self.interval), self._traces.shape[1] - 1)
         if k != self._sweep_k:
-            with self._jax.experimental.enable_x64():
+            with self._x64():
                 util = self._traces[:, k]                        # [V] one sweep
                 demand_vec = util * self._vm_mips                # [V] one sweep
             self._sweep_k = k

@@ -306,13 +306,12 @@ def _executor(fn: Callable, devices: tuple, donate: bool,
     donate_argnums = (0,) if donate else ()
     if len(devices) > 1:
         if sharding == "shard_map":
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import Mesh, PartitionSpec
             mesh = Mesh(np.array(list(devices)), ("lanes",))
             spec = PartitionSpec("lanes")
-            # check_rep=False: lax.while_loop has no replication rule yet.
-            lanes = shard_map(fn, mesh=mesh, in_specs=(spec,),
-                              out_specs=spec, check_rep=False)
+            # check_vma=False: lanes are independent, nothing is replicated.
+            lanes = jax.shard_map(fn, mesh=mesh, in_specs=(spec,),
+                                  out_specs=spec, check_vma=False)
             return jax.jit(lanes, donate_argnums=donate_argnums)
         return jax.pmap(fn, devices=list(devices),
                         donate_argnums=donate_argnums)

@@ -28,12 +28,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels.ops import masked_argmax
-from ..kernels.step import StepSpec, body_from_step
 from .backend import SimBackend, scenario
 from .cluster import FleetConfig, RunStats, StepCost, fleet_fault_windows
 from .faults import FaultPlan
-from .vec_engine import BatchPlan, Done, Loop, VecEngine, make_batch_entry, \
-    resolve_precision
+from .vec_engine import (BatchPlan, Done, Loop, StepSpec, VecEngine,
+                         body_from_step, make_batch_entry, resolve_precision,
+                         x64)
 
 STALL_RETRY_S = 60.0          # matches FleetSim's stall-retry cadence
 
@@ -484,7 +484,7 @@ def _prepare_fleet(cost: StepCost, cfg: FleetConfig, total_steps: int = 2000,
                      start=bcw(w[:, 1]), end=bcw(w[:, 2]))
     else:
         fx = None
-    with jax.experimental.enable_x64():
+    with x64():
         # Keys and (for "fast") the pre-drawn schedules are built in the
         # x64 world either way, so both precisions see the same sample.
         keys = np.asarray(jax.vmap(jax.random.PRNGKey)(jnp.asarray(seeds)))

@@ -31,7 +31,7 @@ a ``prepare(...)`` function maps the public signature to a :class:`BatchPlan`
 (params + statics + predicted cost + host-side finalizer) or short-circuits
 a degenerate batch with :class:`Done`; the builder resolves ``use_pallas``
 (:func:`repro.kernels.ops.resolve_use_pallas`) and ``precision``
-(:func:`resolve_precision`), runs the plan under ``enable_x64`` through
+(:func:`resolve_precision`), runs the plan under :func:`x64` through
 :func:`repro.core.sweep.execute_sweep` (chunking, buffer donation, device
 sharding, divergence bucketing — all bit-identical to a monolithic call),
 plumbs ``with_report``, and registers the ``@scenario`` handler.
@@ -43,7 +43,7 @@ SoA conventions every engine definition follows (the contracts tests assert):
      ``vmap`` (the driver's loop);
   3. next event = masked min/argmin reduction (``ops.*``), not a heap walk;
   4. stochastic processes pre-drawn as absolute schedules in ``build``;
-  5. ``enable_x64`` so decision/number identity with the OO engines holds
+  5. :func:`x64` so decision/number identity with the OO engines holds
      (the driver enters it around every dispatch);
   6. compile-time feature pruning via statics flags (``build`` runs at trace
      time — plain Python ``if`` drops whole subgraphs).
@@ -53,6 +53,7 @@ example; ``vec_netdc`` is the smallest real definition in the tree.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import itertools
 from dataclasses import dataclass
@@ -63,10 +64,55 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels.ops import MaskedOps, pallas_native, resolve_use_pallas
-from ..kernels.step import StepSpec, fused_scan, fused_step_body
 from .backend import scenario
 from .sweep import (MIN_CHUNK, SweepReport, compact_sweep, execute_sweep,
                     resolve_devices)
+
+
+@contextlib.contextmanager
+def x64():
+    """The scope every vec engine traces and dispatches in — the one place
+    the repo turns x64 on, so decision/number identity with the OO engines
+    holds (convention 5 above).  It also pins the non-partitionable
+    threefry stream: the engines' pre-drawn stochastic schedules (and the
+    golden fixtures recorded from them) are that stream's draws."""
+    with jax.enable_x64(True), jax.threefry_partitionable(False):
+        yield
+
+
+class StepSpec(NamedTuple):
+    """An engine's fusion-eligible step declaration (``Loop.step_kernel``).
+
+    ``step(state, stream_slices, it) -> state`` is the *whole* loop body
+    as a pure function of the carried state pytree, this iteration's
+    stream slices, and the driver's int32 counter ``it``.  ``streams`` is
+    a pytree of per-iteration input arrays with the iteration axis first
+    (``[T, ...]``) — empty for engines whose body needs no per-step table
+    (the jnp path reads ``leaf[it]``; the scan kernel blocks the leaf
+    per-step so Pallas prefetches it HBM→VMEM ahead of the compute).
+
+    The contract (what a ``Loop`` must declare for fusion eligibility):
+    ``step`` must be the single source of truth for the body — the jnp
+    ``Loop.body`` must be :func:`body_from_step` of the same spec — and
+    must hold the substrate's SoA invariants: fixed-shape state leaves,
+    no data-dependent shapes, and any nested masked reductions in plain
+    jnp (``MaskedOps(False)`` — a nested ``pallas_call`` cannot lower
+    from inside a kernel; the driver hands fused builds a jnp ``ops``).
+    The kernels themselves live in :mod:`repro.kernels.step`.
+    """
+
+    step: Callable[[Any, Any, Any], Any]
+    streams: Any = ()
+
+
+def body_from_step(spec: StepSpec) -> Callable[[Any, Any], Any]:
+    """The canonical jnp ``Loop.body`` for a :class:`StepSpec`: slice each
+    stream at ``it`` and apply ``step``.  Engines derive their body from
+    this so the fused and jnp paths share one op sequence."""
+    def body(state, it):
+        sl = jax.tree_util.tree_map(lambda a: a[it], spec.streams)
+        return spec.step(state, sl, it)
+    return body
 
 
 class Loop(NamedTuple):
@@ -88,7 +134,7 @@ class Loop(NamedTuple):
     the while-loop form (its lanes genuinely pause mid-stream).
 
     ``step_kernel`` (optional) declares the body fusion-eligible: a
-    :class:`repro.kernels.step.StepSpec` whose ``step`` the engine also
+    :class:`StepSpec` whose ``step`` the engine also
     derived its jnp ``body`` from (``body_from_step``), so the monolithic
     driver may execute the whole iteration as one Pallas kernel
     (``fused_step_body``) — or, with ``trip_count`` set, the whole loop as
@@ -132,6 +178,15 @@ def run_one(engine: VecEngine, params: Any, statics: Any) -> Dict[str, Any]:
     spec = loop.step_kernel if fuse else None
     interpret = not pallas_native()
 
+    if spec is not None:
+        if not interpret:
+            # See repro.kernels.step: no engine step is built from ops the
+            # TPU lowering implements yet.
+            raise NotImplementedError(
+                f"use_pallas=True: the {engine.kind} step does not lower as "
+                f"a native Pallas kernel on the {jax.default_backend()!r} "
+                f"backend — run it with use_pallas=False")
+        from ..kernels.step import fused_scan, fused_step_body
     if loop.trip_count is not None:
         if spec is not None:
             # Whole loop as ONE pallas_call: VMEM-resident state across
@@ -250,13 +305,12 @@ def segment_step(engine: VecEngine, statics: Any, budget: int,
     core = _segment_sim(engine, statics, budget)
     donate_argnums = (1, 2) if donate else ()
     if len(devices) > 1:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec
         mesh = Mesh(np.array(list(devices)), ("lanes",))
         spec = PartitionSpec("lanes")
-        # check_rep=False: lax.while_loop has no replication rule yet.
-        sharded = shard_map(core, mesh=mesh, in_specs=(spec,) * 4,
-                            out_specs=spec, check_rep=False)
+        # check_vma=False: lanes are independent, nothing is replicated.
+        sharded = jax.shard_map(core, mesh=mesh, in_specs=(spec,) * 4,
+                                out_specs=spec, check_vma=False)
 
         def stepped(lane_params, state, it, fresh, sink_id):
             del sink_id                # retire tap is single-device only
@@ -270,7 +324,7 @@ def segment_step(engine: VecEngine, statics: Any, budget: int,
             # iters) to the registered host sink as the device stream
             # advances.  The payload is bool/int32 only — the io_callback
             # delivery thread does not inherit the dispatcher's
-            # thread-local enable_x64, so 64-bit floats would be
+            # thread-local x64 scope, so 64-bit floats would be
             # canonicalized (silently downcast) in flight.  Result
             # payloads therefore always travel as returned arrays
             # (bit-exact); the callback carries only canonicalization-safe
@@ -330,7 +384,7 @@ def broadcast_cells(seeds, axes: Dict[str, Any]):
 def resolve_precision(precision: str) -> bool:
     """Validate an engine's ``precision`` opt-in → ``fast`` flag.
 
-    ``"exact"`` accumulates in f64 under ``enable_x64`` (bit-identical to
+    ``"exact"`` accumulates in f64 under :func:`x64` (bit-identical to
     the OO engines where promised); ``"fast"`` keeps the f64 stochastic
     sample but runs the loop arithmetic in f32.
     """
@@ -356,7 +410,7 @@ def run_compact(engine: VecEngine, plan: BatchPlan, *, chunk_size=None,
     raw_outputs)`` streams each retired batch; ``progress(done_mask,
     segment_iters)`` — when given — fires from *inside* the compiled step
     via ``io_callback`` as each segment's retire mask materializes.
-    Callers must already be under ``enable_x64`` (``run_plan`` is).
+    Callers must already be under :func:`x64` (``run_plan`` is).
     """
     params, statics = plan.params, plan.statics
     n_cells = int(np.shape(jax.tree_util.tree_leaves(params)[0])[0])
@@ -409,7 +463,7 @@ def run_plan(engine: VecEngine, plan, *, chunk_size=None, devices=None,
         out, report = plan.outputs, empty_report(donate)
     else:
         n_cells = int(np.shape(jax.tree_util.tree_leaves(plan.params)[0])[0])
-        with jax.experimental.enable_x64():
+        with x64():
             if compact and n_cells > 0:
                 out, report = run_compact(
                     engine, plan, chunk_size=chunk_size, devices=devices,

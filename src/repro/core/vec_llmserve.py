@@ -9,6 +9,10 @@ legs, the KV/locality bias, was multiplied host-side into the tables), so
 nothing XLA:CPU could FMA-contract, and ``ops.argmin`` shares the OO
 broker's first-occurrence tie rule.  ``oo`` and ``vec`` therefore agree
 bit-exactly on every output (differential suite + golden fixture).
+Where the backend emulates f64 (the TPU: f32 pairs, not IEEE) the loop
+carries the doubles as their int64 bit patterns and adds, maxes and
+compares them with :mod:`repro.core.f64bits` — exact integer arithmetic
+that yields the IEEE results, so the promise holds on the chip too.
 
 The KV-occupancy counters ride in the carry as i64 (x64 is enabled around
 every dispatch) and the all-ineligible (dropped request) case is handled
@@ -23,6 +27,7 @@ from typing import NamedTuple, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from . import f64bits
 from .faults import FaultPlan, RetryPolicy
 from .llmserve import build_cells, empty_llmserve_outputs, summarize
 from .vec_engine import BatchPlan, Done, Loop, VecEngine, make_batch_entry
@@ -38,6 +43,9 @@ class _Statics(NamedTuple):
     # ``timeout`` of its submit drop out of the eligible set.  All other
     # fault effects arrive pre-baked in the packed ``eligible`` column.
     timeout: float = math.inf
+    # Doubles as int64 bit patterns with f64bits arithmetic (backends
+    # without IEEE f64); the tables and the time outputs then travel as bits.
+    f64_bits: bool = False
 
 
 class _Params(NamedTuple):
@@ -53,7 +61,7 @@ class _Params(NamedTuple):
     same, only their storage layout changes.  Layout per row (see
     :func:`_pack_cells`): ``submit | svc[P·S] | hop[P·S] | tail[P] |
     first_extra[P] | bias[P] | eligible[P] | kv_need``."""
-    packed: jnp.ndarray       # [J, K]    f64
+    packed: jnp.ndarray       # [J, K]    f64 (or its i64 bits, kv_need an i64)
 
 
 def _pack_cells(cells) -> np.ndarray:
@@ -98,6 +106,13 @@ def _llmserve_build(cell, s: _Statics, ops) -> Loop:
     pipes = jnp.arange(s.n_pipelines)
     P, S = s.n_pipelines, s.n_stages
     ps = P * S
+    if s.f64_bits:
+        add, maximum, key = f64bits.add, f64bits.maximum, f64bits.key
+        inf = jnp.int64(f64bits.INF)
+        timeout = jnp.int64(f64bits.bits(s.timeout))
+    else:
+        add, maximum, key = jnp.add, jnp.maximum, (lambda v: v)
+        inf, timeout = jnp.inf, s.timeout
 
     def body(c: _Carry, it) -> _Carry:
         # One dynamic slice fetches everything request `it` needs; the
@@ -109,7 +124,7 @@ def _llmserve_build(cell, s: _Statics, ops) -> Loop:
         tail = row[1 + 2 * ps:1 + 2 * ps + P]
         first_extra = row[1 + 2 * ps + P:1 + 2 * ps + 2 * P]
         bias = row[1 + 2 * ps + 2 * P:1 + 2 * ps + 3 * P]
-        elig = row[1 + 2 * ps + 3 * P:1 + 2 * ps + 4 * P] > 0.5
+        elig = row[1 + 2 * ps + 3 * P:1 + 2 * ps + 4 * P] != 0
         kv_need = row[-1].astype(c.kv_used.dtype)
         # Store-and-forward relay through the pipeline stages (unrolled at
         # trace time): depart(s) = max(free[s], depart(s-1)+hop[s]) + svc[s].
@@ -117,35 +132,34 @@ def _llmserve_build(cell, s: _Statics, ops) -> Loop:
         start_last = d
         deps = []
         for st in range(S):
-            arr = d + hop[:, st]
-            start_last = jnp.maximum(c.free[:, st], arr)
-            d = start_last + svc[:, st]
+            arr = add(d, hop[:, st])
+            start_last = maximum(c.free[:, st], arr)
+            d = add(start_last, svc[:, st])
             deps.append(d)
         dep = jnp.stack(deps, axis=1)                 # [P, S]
-        fin = d + tail
+        fin = add(d, tail)
         if math.isfinite(s.timeout):                  # static: timeout lane
-            elig = elig & (fin <= submit + s.timeout)
-        score = fin + bias
-        pick = ops.argmin(score, elig)
+            elig = elig & (key(fin) <= key(add(submit, timeout)))
+        # Masked like ops.argmin's own fill, in key order for bit patterns.
+        pick = ops.argmin(jnp.where(elig, key(add(fin, bias)), key(inf)))
         ok = jnp.any(elig)
         sel = (pipes[:, None] == pick) & ok           # [P, S]
-        inf = jnp.asarray(jnp.inf, fin.dtype)
         return _Carry(
             free=jnp.where(sel, dep, c.free),
             kv_used=c.kv_used + jnp.where(sel, kv_need, 0),
             dst=c.dst.at[it].set(
                 jnp.where(ok, pick, -1).astype(jnp.int32)),
             finish=c.finish.at[it].set(jnp.where(ok, fin[pick], inf)),
-            ttft=c.ttft.at[it].set(
-                jnp.where(ok, start_last[pick] + first_extra[pick], inf)))
+            ttft=c.ttft.at[it].set(jnp.where(
+                ok, add(start_last[pick], first_extra[pick]), inf)))
 
     dtype = cell.packed.dtype
     return Loop(
         init=_Carry(free=jnp.zeros((P, S), dtype),
                     kv_used=jnp.zeros((P, S), jnp.int64),
                     dst=jnp.full((s.n_requests,), -1, jnp.int32),
-                    finish=jnp.full((s.n_requests,), jnp.inf, dtype),
-                    ttft=jnp.full((s.n_requests,), jnp.inf, dtype)),
+                    finish=jnp.full((s.n_requests,), inf, dtype),
+                    ttft=jnp.full((s.n_requests,), inf, dtype)),
         cond=lambda c, it: it < s.n_requests,
         body=body,
         finalize=lambda c, it: dict(dst=c.dst, finish=c.finish,
@@ -183,7 +197,13 @@ def _prepare_llmserve(*, use_pallas: bool, seeds=(0,), n_machines: int = 6,
             int(n_machines), faulted=fault_plan is not None
             or math.isfinite(timeout_s)))
     fx = cells[0].fx
-    params = _Params(packed=_pack_cells(cells))
+    packed = _pack_cells(cells)
+    exact_bits = not f64bits.native()
+    if exact_bits:
+        kv_need = packed[..., -1].astype(np.int64)
+        packed = f64bits.bits(packed)
+        packed[..., -1] = kv_need
+    params = _Params(packed=packed)
     n_pipes, n_st = cells[0].placement.shape
     n_requests = len(cells[0].submit)  # an injected workload sets its own
     # Every lane routes exactly n_requests requests: nothing to bucket.
@@ -191,8 +211,16 @@ def _prepare_llmserve(*, use_pallas: bool, seeds=(0,), n_machines: int = 6,
                      _Statics(int(n_requests), int(n_pipes), int(n_st),
                               bool(use_pallas),
                               timeout=(fx.timeout_s if fx
-                                       else math.inf)),
-                     finalize=lambda out: summarize(out, cells))
+                                       else math.inf),
+                              f64_bits=exact_bits),
+                     finalize=lambda out: summarize(
+                         _doubles(out) if exact_bits else out, cells))
+
+
+def _doubles(out):
+    """The bit-pattern route's time outputs back as doubles."""
+    return dict(out, **{k: f64bits.doubles(out[k])
+                        for k in ("finish", "ttft")})
 
 
 simulate_llmserve_batch = make_batch_entry(

@@ -37,13 +37,13 @@ from typing import Any, Dict, NamedTuple, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.step import StepSpec, body_from_step
 from .backend import scenario
 from .faults import FaultPlan
 from .power import (_broadcast_cells, _empty_outputs, _finalize,
                     _finalize_accumulators, _power_batch_oo,
                     make_power_fleet, power_fault_table, power_points)
-from .vec_engine import BatchPlan, Done, Loop, VecEngine, make_batch_entry
+from .vec_engine import (BatchPlan, Done, Loop, StepSpec, VecEngine,
+                         body_from_step, make_batch_entry)
 
 
 @dataclass(frozen=True)
@@ -110,7 +110,7 @@ def _power_build(params: _Params, s: _Statics, ops) -> Loop:
 
     The body is declared as a fusion-eligible *step* over per-interval
     streams (the demand trace, and the crash table when faulted): the jnp
-    ``body`` is :func:`~repro.kernels.step.body_from_step` of the same
+    ``body`` is :func:`~repro.core.vec_engine.body_from_step` of the same
     step, and the returned ``Loop`` carries ``trip_count`` +
     ``step_kernel`` so the driver may run the whole trace as one Pallas
     scan kernel (streams double-buffered HBM→VMEM per interval) with

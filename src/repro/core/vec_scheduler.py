@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from ..kernels.ops import masked_min
 from .backend import SimBackend, scenario
-from .vec_engine import BatchPlan, Loop, VecEngine, make_batch_entry
+from .vec_engine import BatchPlan, Loop, VecEngine, make_batch_entry, x64
 
 INF = jnp.inf
 
@@ -150,7 +150,7 @@ def simulate_batch(length, pes, submit, guest_mips, guest_pes,
     submit = np.asarray(submit, np.float64)
     order, inv = _canonical_order(submit)
     g_idx = np.arange(length.shape[0])[:, None]
-    with jax.experimental.enable_x64():
+    with x64():
         guest_mips = jnp.asarray(guest_mips, jnp.float64)
         guest_pes = jnp.asarray(guest_pes, jnp.float64)
         st = simulate(make_state(length[g_idx, order], pes[g_idx, order],

@@ -8,5 +8,6 @@
 #                   (per-step fused body + static-trip-count scan)
 #   flash_attention.py / rwkv6_scan.py — model-stack kernels
 #   ops.py        — public adapters + the use_pallas resolution switch
-from .step import (StepSpec, body_from_step, fused_scan,  # noqa: F401
-                   fused_step_body)
+#
+# Nothing is imported here: the vec engines' plain path must not load
+# Pallas, so each kernel module is imported where its route is taken.

@@ -14,15 +14,18 @@ outputs ``[R]`` min values and ``[R]`` int32 argmins (first occurrence on
 ties, matching ``jnp.argmin``).  Masked-out / padded slots are ``+inf``; an
 all-inf row returns ``(inf, 0)`` exactly like ``jnp.argmin``.
 
-Tiling: each program reduces a ``(rows_per_block, block)`` tile; the grid's
-minor axis walks the M tiles sequentially so the per-row ``[rows, 1]``
-accumulators carry across tiles.  ``rows_per_block`` is picked from the
-input shape — one row per program when M fills a whole tile, many rows when
-M is small (the common sweep shape, R ≫ M, where one-row programs would
-waste nearly every vector lane).
+Tiling: each program reduces a ``(rows, blk)`` tile; the grid's minor axis
+walks the M tiles sequentially so the per-row ``[rows, 1]`` accumulators
+carry across tiles.  The TPU lowering takes a tile whose last two
+dimensions are multiples of 8 and 128 or the whole array's, so ``blk`` is
+the whole M when M fits one block and a multiple of 128 otherwise, and
+``rows`` is the whole R when R fits one tile and a multiple of 8 otherwise.
+``rows`` targets ~``block`` elements per program: many rows when M is small
+(the common sweep shape, R ≫ M, where one-row programs would waste nearly
+every vector lane).
 
-CPU runs interpret mode (tests, the x64 bit-exact scheduler path — f64 is
-interpreter-only; TPU lowering targets f32).
+The TPU lowering reduces float32 only; f64 runs in interpret mode (CPU
+tests of the x64 bit-exact engines).
 """
 from __future__ import annotations
 
@@ -45,8 +48,13 @@ def _next_event_kernel(t_ref, vmin_ref, imin_ref, *, block: int):
 
     t = t_ref[...]                                    # [rows, block]
     bmin = jnp.min(t, axis=1, keepdims=True)          # [rows, 1]
-    barg = jnp.argmin(t, axis=1).astype(jnp.int32)    # first-occurrence ties
-    bidx = j * block + barg[:, None]
+    # First occurrence of the minimum as the least column holding it: the
+    # TPU's own argmin does not keep jnp.argmin's tie rule.  int32
+    # throughout, even under x64 — all the TPU lowering takes.
+    cols = jax.lax.broadcasted_iota(jnp.int32, t.shape, 1)
+    barg = jnp.min(jnp.where(t == bmin, cols, jnp.int32(block)), axis=1,
+                   keepdims=True)
+    bidx = j * block + barg
     cur = vmin_ref[...]
     better = bmin < cur                # strict ⇒ earliest block wins ties
     imin_ref[...] = jnp.where(better, bidx, imin_ref[...])
@@ -55,10 +63,22 @@ def _next_event_kernel(t_ref, vmin_ref, imin_ref, *, block: int):
 
 def _auto_rows(r: int, blk: int, block: int) -> int:
     """Rows per program tile: target ~``block`` elements of work per
-    program.  M ≥ block ⇒ one row (the tile is already full); small M ⇒
-    ``block // M`` rows so wide sweeps don't run one near-empty program
-    per row."""
-    return max(1, min(block // max(blk, 1), max(r, 1)))
+    program — ``block // M`` rows for small M, so wide sweeps don't run
+    one near-empty program per row — as a multiple of 8, or all of R when
+    R fits one tile."""
+    return _legal_rows(r, block // max(blk, 1))
+
+
+def _legal_rows(r: int, rows: int) -> int:
+    """``rows`` rounded up to the TPU's sublane multiple (8), or all of R."""
+    rows = -(-max(int(rows), 1) // 8) * 8
+    return max(r, 1) if rows >= r else rows
+
+
+def _row_block(i, j):
+    # An int32 zero: under x64 a bare 0 would make the index map return
+    # an i64, which the TPU lowering cannot legalize.
+    return i, jnp.int32(0)
 
 
 def next_event(times: jax.Array, mask: jax.Array | None = None, *,
@@ -72,15 +92,21 @@ def next_event(times: jax.Array, mask: jax.Array | None = None, *,
     but as one fused pass.  ``rows_per_block=None`` picks the row tiling
     from the input shape (see :func:`_auto_rows`).
     """
+    if not interpret and times.dtype != jnp.float32:
+        raise NotImplementedError(
+            f"the native next-event kernel reduces float32 only, not "
+            f"{times.dtype}: the x64 exact engines run use_pallas=False on "
+            f"the {jax.default_backend()!r} backend")
     if mask is not None:
         times = jnp.where(mask, times, jnp.asarray(jnp.inf, times.dtype))
     lead = times.shape[:-1]
     m = times.shape[-1]
     t2 = times.reshape((-1, m))
     r = t2.shape[0]
-    blk = min(block, max(m, 1))
+    # The whole M in one tile, or lane-aligned (multiple of 128) tiles.
+    blk = max(m, 1) if m <= block else max(block // 128, 1) * 128
     rows = (_auto_rows(r, blk, block) if rows_per_block is None
-            else max(1, min(int(rows_per_block), max(r, 1))))
+            else _legal_rows(r, rows_per_block))
     pad_m = (-m) % blk
     pad_r = (-r) % rows
     if pad_m or pad_r:
@@ -95,8 +121,8 @@ def next_event(times: jax.Array, mask: jax.Array | None = None, *,
                    jax.ShapeDtypeStruct((r_pad, 1), jnp.int32)),
         grid=(r_pad // rows, t2.shape[1] // blk),
         in_specs=[pl.BlockSpec((rows, blk), lambda i, j: (i, j))],
-        out_specs=(pl.BlockSpec((rows, 1), lambda i, j: (i, 0)),
-                   pl.BlockSpec((rows, 1), lambda i, j: (i, 0))),
+        out_specs=(pl.BlockSpec((rows, 1), _row_block),
+                   pl.BlockSpec((rows, 1), _row_block)),
         interpret=interpret,
     )(t2)
     return vmin[:r, 0].reshape(lead), imin[:r, 0].reshape(lead)

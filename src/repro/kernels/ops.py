@@ -9,8 +9,9 @@ The vec simulation engines gate their ``use_pallas`` opt-in through
 which is strictly slower than the plain XLA reduction (the committed
 ``BENCH_substrate.json`` once recorded the opt-in costing 3.5×), so the
 opt-in auto-falls back to the jnp path with a one-time warning.  Pass
-``use_pallas="force"`` to run the interpret-mode kernel anyway (kernel
-tests, TPU-lowering dry runs).
+``use_pallas="force"`` to run the interpret-mode kernel anyway (CPU kernel
+tests).  Where Pallas lowers natively the opt-in never falls back: a route
+that cannot lower there raises when it is traced, before anything runs.
 """
 from __future__ import annotations
 
@@ -19,10 +20,6 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
-
-from .flash_attention import flash_attention
-from .next_event import next_event
-from .rwkv6_scan import wkv6
 
 _PALLAS_BACKENDS = ("tpu", "gpu")
 # Backends we have already warned about falling back on — per backend, so
@@ -46,15 +43,24 @@ def pallas_native() -> bool:
 def resolve_use_pallas(use_pallas) -> bool:
     """Resolve an engine's ``use_pallas`` opt-in against the backend.
 
-    ``False`` stays off.  ``True`` enables the fused kernels only where
-    they lower natively; on CPU (interpret mode — slower than the plain
-    reduction) it falls back to the jnp path with a warning (once per
-    backend; :func:`reset_pallas_warning` re-arms it).
-    ``"force"`` always enables them (interpret mode on CPU).
+    ``False`` stays off.  ``True`` enables the kernels natively where
+    Pallas lowers (a route that cannot lower there raises, never falls
+    back); on CPU (interpret mode — slower than the plain reduction) it
+    falls back to the jnp path with a warning (once per backend;
+    :func:`reset_pallas_warning` re-arms it).  ``"force"`` runs the
+    interpret-mode kernels on CPU, for tests; a native backend rejects it.
     """
     if not use_pallas:
         return False
-    if use_pallas == "force" or pallas_native():
+    if pallas_native():
+        if use_pallas == "force":
+            raise ValueError(
+                "use_pallas='force' runs the Pallas kernels in interpret "
+                "mode, for CPU tests; on the "
+                f"{jax.default_backend()!r} backend pass use_pallas=True "
+                "(native kernels) or False")
+        return True
+    if use_pallas == "force":
         return True
     backend = jax.default_backend()
     if backend not in _warned_pallas_fallback:
@@ -72,6 +78,7 @@ def resolve_use_pallas(use_pallas) -> bool:
 def attention_op(q: jax.Array, k: jax.Array, v: jax.Array, *,
                  causal: bool = True, interpret: bool = False) -> jax.Array:
     """Model layout adapter: q [B,S,H,hd], k/v [B,S,K,hd] → [B,S,H,hd]."""
+    from .flash_attention import flash_attention
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
@@ -88,6 +95,7 @@ def next_event_op(times: jax.Array, mask: jax.Array | None = None, *,
     ``interpret=None`` resolves automatically: native lowering on TPU/GPU,
     interpret mode elsewhere (reached only via ``use_pallas="force"``).
     """
+    from .next_event import next_event
     if interpret is None:
         interpret = not pallas_native()
     return next_event(times, mask, interpret=interpret)
@@ -168,6 +176,7 @@ def wkv6_op(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
             u: jax.Array, *, interpret: bool = False):
     """Model layout adapter: r/k/v/logw [B,S,H,N], u [H,N] →
     (y [B,S,H,N], state [B,H,N,N])."""
+    from .rwkv6_scan import wkv6
     tr = lambda t: t.transpose(0, 2, 1, 3)
     y, state = wkv6(tr(r), tr(k), tr(v), tr(logw), u, interpret=interpret)
     return y.transpose(0, 2, 1, 3), state

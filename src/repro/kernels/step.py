@@ -22,79 +22,41 @@ flash-attention from naive attention:
     the compute on real hardware — the HBM→VMEM prefetch for large tables.
 
 An engine opts in declaratively: its ``build`` returns the loop with a
-:class:`StepSpec` in ``Loop.step_kernel`` and derives its jnp ``body`` from
-the *same* step function via :func:`body_from_step` — both paths execute
-one op sequence, so bit-exactness vs the jnp path holds by construction
-(asserted by ``tests/test_step_kernel.py``).
+:class:`repro.core.vec_engine.StepSpec` in ``Loop.step_kernel`` and derives
+its jnp ``body`` from the *same* step function via
+:func:`repro.core.vec_engine.body_from_step` — both paths execute one op
+sequence, so bit-exactness vs the jnp path holds by construction (asserted
+by ``tests/test_step_kernel.py``).  The driver imports this module only
+when it takes a fused route, so the plain path never loads Pallas.
 
 Mechanics worth knowing:
 
   * **Closure conversion** — engine bodies close over traced values
     (pre-drawn schedules, PRNG keys, parameter leaves).  Pallas rejects
     kernels capturing array constants, and ``jax.closure_convert`` only
-    hoists *differentiable* consts (its ``_maybe_perturbed`` partition
-    leaves e.g. uint32 PRNG keys baked in), so
-    :func:`closure_convert_all` re-implements the hoist with the same
-    tracing machinery but lifts **every** const into a kernel operand.
+    hoists *differentiable* consts (uint32 PRNG keys stay baked in), so
+    :func:`closure_convert_all` traces with ``jax.make_jaxpr`` — whose
+    ``ClosedJaxpr.consts`` holds **every** const — and lifts each into a
+    kernel operand.
   * **Scalar padding** — Pallas refs are at least rank 1; 0-d state
     leaves/consts are padded to ``(1,)`` at the call boundary and
     reshaped back inside the kernel and after the call.
   * **Interpret vs native** — on CPU the kernels only run in interpret
     mode (strictly slower than the XLA loop; reached via
-    ``use_pallas="force"`` — see ``resolve_use_pallas``); on TPU/GPU
-    (``pallas_native()``) they lower natively.  f64 state is
-    interpreter-only; native lowering targets f32 engines.
+    ``use_pallas="force"`` — see ``resolve_use_pallas``).  The TPU
+    lowering takes f32 steps built from ops it implements; no engine's
+    step is one yet (power carries f64 state; the fleet step uses cumsum,
+    sort and in-kernel threefry), so the driver refuses fused routes on a
+    native backend.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, NamedTuple
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# Private-API imports for closure_convert_all: the public
-# jax.closure_convert drops non-differentiable consts (see module
-# docstring); these are the exact pieces it is itself built from.
-from jax._src import core as _jcore
-from jax._src import linear_util as _lu
-from jax._src.api_util import flatten_fun_nokwargs, shaped_abstractify
-from jax._src.interpreters import partial_eval as _pe
-
-
-class StepSpec(NamedTuple):
-    """An engine's fusion-eligible step declaration (``Loop.step_kernel``).
-
-    ``step(state, stream_slices, it) -> state`` is the *whole* loop body
-    as a pure function of the carried state pytree, this iteration's
-    stream slices, and the driver's int32 counter ``it``.  ``streams`` is
-    a pytree of per-iteration input arrays with the iteration axis first
-    (``[T, ...]``) — empty for engines whose body needs no per-step table
-    (the jnp path reads ``leaf[it]``; the scan kernel blocks the leaf
-    per-step so Pallas prefetches it HBM→VMEM ahead of the compute).
-
-    The contract (what a ``Loop`` must declare for fusion eligibility):
-    ``step`` must be the single source of truth for the body — the jnp
-    ``Loop.body`` must be :func:`body_from_step` of the same spec — and
-    must hold the substrate's SoA invariants: fixed-shape state leaves,
-    no data-dependent shapes, and any nested masked reductions in plain
-    jnp (``MaskedOps(False)`` — a nested ``pallas_call`` cannot lower
-    from inside a kernel; the driver hands fused builds a jnp ``ops``).
-    """
-
-    step: Callable[[Any, Any, Any], Any]
-    streams: Any = ()
-
-
-def body_from_step(spec: StepSpec) -> Callable[[Any, Any], Any]:
-    """The canonical jnp ``Loop.body`` for a :class:`StepSpec`: slice each
-    stream at ``it`` and apply ``step``.  Engines derive their body from
-    this so the fused and jnp paths share one op sequence."""
-    def body(state, it):
-        sl = jax.tree_util.tree_map(lambda a: a[it], spec.streams)
-        return spec.step(state, sl, it)
-    return body
 
 
 def closure_convert_all(fun: Callable, *example_args):
@@ -103,18 +65,18 @@ def closure_convert_all(fun: Callable, *example_args):
     Pallas-kernel-clean.  Returns ``(converted, consts)`` where
     ``converted(*flat_args, *consts)`` replays the traced computation."""
     flat_args, in_tree = jax.tree_util.tree_flatten(example_args)
-    in_avals = tuple(shaped_abstractify(x) for x in flat_args)
-    wrapped, out_tree = flatten_fun_nokwargs(_lu.wrap_init(fun), in_tree)
-    jaxpr, _, consts, () = _pe.trace_to_jaxpr_dynamic(wrapped, in_avals)
-    otree = out_tree()
+    closed, out_shape = jax.make_jaxpr(
+        lambda *flat: fun(*jax.tree_util.tree_unflatten(in_tree, flat)),
+        return_shape=True)(*flat_args)
+    otree = jax.tree_util.tree_structure(out_shape)
     n_args = len(flat_args)
 
     def converted(*args_consts):
         args, cs = args_consts[:n_args], args_consts[n_args:]
-        out = _jcore.eval_jaxpr(jaxpr, list(cs), *args)
+        out = jax.core.eval_jaxpr(closed.jaxpr, list(cs), *args)
         return jax.tree_util.tree_unflatten(otree, out)
 
-    return converted, list(consts)
+    return converted, list(closed.consts)
 
 
 def _pad(a):
@@ -127,7 +89,7 @@ def _pad_shape(s):
     return (1,) if s == () else tuple(s)
 
 
-def fused_step_body(spec: StepSpec, *, interpret: bool = True
+def fused_step_body(spec, *, interpret: bool = True
                     ) -> Callable[[Any, Any], Any]:
     """One whole loop iteration as a single ``pallas_call`` —
     drop-in replacement for :func:`body_from_step`'s jnp body inside the
@@ -174,7 +136,7 @@ def fused_step_body(spec: StepSpec, *, interpret: bool = True
     return body
 
 
-def fused_scan(spec: StepSpec, init: Any, trip_count: int, *,
+def fused_scan(spec, init: Any, trip_count: int, *,
                interpret: bool = True):
     """The whole static-trip-count loop as **one** ``pallas_call``.
 

@@ -31,6 +31,20 @@ def test_unknown_scenario_raises():
         run_scenario("time-travel", backend="oo")
 
 
+def test_failed_scenario_import_raises_on_every_call(monkeypatch):
+    """A scenario module that fails to import must raise its ImportError on
+    every call, not once and then "unknown scenario kind" forever after."""
+    from repro.core import backend
+    monkeypatch.setattr(backend, "_loaded", False)
+    monkeypatch.setattr(backend, "_SCENARIO_MODULES",
+                        backend._SCENARIO_MODULES
+                        + ("repro.core._no_such_scenarios",))
+    for _ in range(2):
+        with pytest.raises(ModuleNotFoundError, match="_no_such_scenarios"):
+            run_scenario("power_batch", backend="oo", seeds=[0])
+    assert backend._loaded is False
+
+
 def test_scenario_kinds_registered():
     kinds = scenario_kinds()
     for k in ("consolidation", "fleet", "fleet_batch", "case_study",

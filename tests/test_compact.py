@@ -226,7 +226,7 @@ def _run_two_device(code: str) -> str:
     env = dict(os.environ,
                XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
                           + " --xla_force_host_platform_device_count=2"),
-               PYTHONPATH=os.pathsep.join(sys.path))
+               PYTHONPATH=os.pathsep.join(sys.path), JAX_PLATFORMS="cpu")
     proc = subprocess.run([sys.executable, "-c", _SUBPROC_PRELUDE + code],
                           env=env, capture_output=True, text=True,
                           timeout=300)

@@ -174,12 +174,15 @@ def _cmp_netdc(oo, vec):
 def _gen_llmserve(rng):
     n_stages = int(rng.integers(1, 4))
     n_machines = int(rng.integers(n_stages, 4 * n_stages + 1))
-    return dict(seeds=rng.integers(0, 1000, 3),
-                n_machines=n_machines, n_regions=int(rng.integers(1, 5)),
+    seeds = rng.integers(0, 1000, 3)
+    n_regions = int(rng.integers(1, 5))
+    return dict(seeds=seeds,
+                n_machines=n_machines, n_regions=n_regions,
                 n_stages=n_stages, n_requests=int(rng.integers(8, 40)),
                 mean_gap_s=float(rng.uniform(0.1, 3.0)),
                 locality_weight=float(rng.uniform(0.5, 4.0)),
-                offline_region=int(rng.integers(-1, 2)),
+                # an outage only of a region that exists
+                offline_region=min(int(rng.integers(-1, 2)), n_regions - 1),
                 offline_frac=float(rng.uniform(0.0, 1.0)),
                 kv_penalty_s=float(rng.uniform(0.0, 2.0)),
                 # straddle the pipeline KV capacities so drops occur
@@ -437,3 +440,22 @@ if HAVE_HYPOTHESIS:
     @pytest.mark.parametrize("kind", sorted(CASES))
     def test_differential_hypothesis(kind, seed):
         _check(kind, seed)
+
+
+# -- llmserve's exact bit-pattern route (backends that emulate f64) ------------
+
+@pytest.mark.parametrize("trial", range(3))
+@pytest.mark.parametrize("gen", [_gen_llmserve, _gen_llmserve_faulted],
+                         ids=["clean", "faulted"])
+def test_llmserve_f64_bits_route(monkeypatch, gen, trial):
+    """The route the TPU takes — doubles as int64 bit patterns through
+    ``f64bits`` — forced here on the CPU: still bit-exact vs ``oo``,
+    monolithic and compacting."""
+    from repro.core import f64bits
+    params = gen(np.random.default_rng(104729 * trial + 11))
+    oo = _run_llmserve("oo", params)
+    monkeypatch.setattr(f64bits, "native", lambda: False)
+    _cmp_llmserve(oo, _run_llmserve("vec", params))
+    compact = _run_llmserve("vec", dict(params, compact=True, chunk_size=2,
+                                        segment_iters=5))
+    _cmp_llmserve(oo, compact)

@@ -138,10 +138,12 @@ def test_next_event_row_tiling(shape, rows):
 
 def test_next_event_auto_rows_heuristic():
     """Auto tiling targets ~block elements per program: many rows when M
-    is small, one row when M fills the tile."""
+    is small, the TPU's minimum of 8 rows when M fills the tile, and
+    always a multiple of 8 or the whole R."""
     from repro.kernels.next_event import DEFAULT_BLOCK, _auto_rows
     assert _auto_rows(4096, 8, DEFAULT_BLOCK) == DEFAULT_BLOCK // 8
-    assert _auto_rows(4096, DEFAULT_BLOCK, DEFAULT_BLOCK) == 1
+    assert _auto_rows(4096, DEFAULT_BLOCK, DEFAULT_BLOCK) == 8
+    assert _auto_rows(4096, 24, DEFAULT_BLOCK) == 24     # 21 → next 8-multiple
     assert _auto_rows(2, 8, DEFAULT_BLOCK) == 2          # clamped to R
     assert _auto_rows(0, 8, DEFAULT_BLOCK) == 1          # degenerate floor
 
@@ -149,7 +151,8 @@ def test_next_event_auto_rows_heuristic():
 def test_next_event_f64_and_vmap():
     """The engine paths run the kernel under x64 (bit-exact scheduler) and
     under vmap (batched fleet sweeps)."""
-    with jax.experimental.enable_x64():
+    from repro.core.vec_engine import x64
+    with x64():
         t = jnp.asarray(jax.random.uniform(RNG, (3, 50)), jnp.float64)
         v, i = next_event_op(t, interpret=True)
         assert v.dtype == jnp.float64

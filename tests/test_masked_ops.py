@@ -7,22 +7,18 @@ first-occurrence tie-breaking, and bit-exact jnp-vs-Pallas agreement.
 """
 import warnings
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.vec_engine import x64
 from repro.kernels.ops import (MaskedOps, masked_argmax, masked_argmin,
                                masked_min, pallas_native,
                                reset_pallas_warning, resolve_use_pallas)
 
 
-def _x64():
-    return jax.experimental.enable_x64()
-
-
 def test_masked_min_basic_and_mask():
-    with _x64():
+    with x64():
         v = jnp.asarray([3.0, 1.0, 2.0, 0.5])
         assert float(masked_min(v)) == 0.5
         m = jnp.asarray([True, True, True, False])
@@ -34,7 +30,7 @@ def test_masked_min_basic_and_mask():
 def test_all_masked_returns_inf_and_index_zero():
     """An all-masked row behaves exactly like jnp.min/argmin over all-inf:
     (inf, 0) — the engines rely on this for 'no candidate events left'."""
-    with _x64():
+    with x64():
         v = jnp.asarray([5.0, 7.0, 9.0])
         m = jnp.zeros(3, bool)
         assert np.isinf(float(masked_min(v, m)))
@@ -43,7 +39,7 @@ def test_all_masked_returns_inf_and_index_zero():
 
 
 def test_first_occurrence_tie_breaking():
-    with _x64():
+    with x64():
         v = jnp.asarray([4.0, 2.0, 2.0, 4.0])
         assert int(masked_argmin(v)) == 1
         assert int(masked_argmax(v)) == 0
@@ -56,7 +52,7 @@ def test_first_occurrence_tie_breaking():
 
 
 def test_last_axis_reduction_with_leading_dims():
-    with _x64():
+    with x64():
         v = jnp.asarray([[3.0, 1.0], [2.0, 5.0]])
         assert np.array_equal(np.asarray(masked_min(v)), [1.0, 2.0])
         assert np.array_equal(np.asarray(masked_argmin(v)), [1, 0])
@@ -69,7 +65,7 @@ def test_jnp_vs_pallas_agree_bitwise(op):
     path — value *and* tie-broken index — over randomized masked inputs
     (duplicates injected to exercise the tie rule)."""
     rng = np.random.default_rng(42)
-    with _x64():
+    with x64():
         for trial in range(5):
             n = int(rng.integers(2, 40))
             v = rng.choice([0.25, 1.5, 3.0, 7.25], size=n)  # forced ties
@@ -81,7 +77,7 @@ def test_jnp_vs_pallas_agree_bitwise(op):
 
 
 def test_maskedops_binds_the_switch():
-    with _x64():
+    with x64():
         v = jnp.asarray([2.0, 1.0, 1.0])
         for up in (False, True):
             ops = MaskedOps(use_pallas=up)
@@ -134,3 +130,41 @@ def test_pallas_fallback_warning_once_per_backend_and_reset():
             ops_mod.jax.default_backend = real
         assert len(caught) == 2
     reset_pallas_warning()
+
+
+# -- a native backend: no silent fallback -----------------------------------------
+#
+# Where Pallas lowers natively, use_pallas=True never falls back to the jnp
+# path or interpret mode: a route that cannot lower raises before anything
+# runs.  The CPU stands in for the chip by answering "tpu" to the backend
+# query the routing reads; nothing here reaches a device kernel.
+
+@pytest.fixture
+def native(monkeypatch):
+    import repro.kernels.ops as ops_mod
+    monkeypatch.setattr(ops_mod.jax, "default_backend", lambda: "tpu")
+    assert pallas_native()
+
+
+def test_native_rejects_force(native):
+    with pytest.raises(ValueError, match="interpret mode"):
+        resolve_use_pallas("force")
+    assert resolve_use_pallas(True) is True
+
+
+def test_native_f64_reduction_raises(native):
+    """An x64 exact engine's reductions are f64: the TPU kernel lowers
+    float32 only, so the request is refused, not rerouted."""
+    from repro.core.vec_netdc import simulate_netdc_batch
+    with pytest.raises(NotImplementedError, match="float32 only"):
+        simulate_netdc_batch(seeds=[0, 1, 2], n_dcs=5, n_jobs=7,
+                             use_pallas=True)
+
+
+def test_native_fused_step_raises(native):
+    """No engine step lowers as a native kernel yet: power asks for the
+    whole-loop scan kernel and is told to run use_pallas=False."""
+    from repro.core.vec_power import simulate_power_batch
+    with pytest.raises(NotImplementedError, match="use_pallas=False"):
+        simulate_power_batch(seeds=[0, 1, 2], n_hosts=5, n_vms=9,
+                             n_samples=7, use_pallas=True)

@@ -122,6 +122,6 @@ def test_filter_respected(xs):
 def test_resolve_spec_never_errors(d1, d2):
     import jax
     from repro.distributed.sharding import LOGICAL_RULES_BASE, resolve_spec
-    mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 4)))
+    mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     spec = resolve_spec((d1, d2), ("mlp", "embed"), mesh, LOGICAL_RULES_BASE)
     assert len(spec) == 2

@@ -13,6 +13,8 @@ import tempfile
 import pytest
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+# Children run on the CPU backend only: none of them may reach for a chip.
+CHILD_ENV = dict(os.environ, JAX_PLATFORMS="cpu")
 
 
 # -- resolve_spec properties -----------------------------------------------------
@@ -20,9 +22,8 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 def _mesh(shape=(2, 4), axes=("data", "model")):
     # AbstractMesh: resolve_spec/cache_spec only read mesh.shape, and the
     # main test process has a single CPU device (no 8-device mesh possible).
-    # (jax 0.4.37 signature: a tuple of (axis_name, size) pairs.)
     import jax
-    return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 def test_resolve_divisibility_fallback():
@@ -69,10 +70,11 @@ sys.path.insert(0, {src!r})
 import jax
 from repro.configs.base import load_tiny, ShapeConfig
 from repro.launch.steps import build_cell
+from repro.launch.mesh import make_mesh
 from repro.launch.roofline import collective_bytes_per_device, cost_of
 
 cfg = dataclasses.replace(load_tiny({arch!r}), scan_layers=False)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh(2, 4)
 shape = ShapeConfig("t", 64, 8, {kind!r})
 with mesh:
     fn, args = build_cell(cfg, shape, mesh)
@@ -89,7 +91,7 @@ print(json.dumps({{"cost": cost_of(compiled), "coll_total": coll["total"]}}))
 def test_mini_dryrun_subprocess(arch, kind):
     code = DRYRUN_SNIPPET.format(src=os.path.abspath(SRC), arch=arch, kind=kind)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, timeout=600)
+                         text=True, timeout=600, env=CHILD_ENV)
     assert out.returncode == 0, out.stderr[-2000:]
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert rec["cost"]["flops"] > 0
@@ -111,7 +113,7 @@ assert dict(m2.shape) == {{"pod": 2, "data": 16, "model": 16}}, m2.shape
 print("ok")
 """.format(src=os.path.abspath(SRC))
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, timeout=300)
+                         text=True, timeout=300, env=CHILD_ENV)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "ok" in out.stdout
 
@@ -127,7 +129,8 @@ from repro.models.model import build
 from repro.optim import make_optimizer
 from repro.train.dp_step import make_dp_train_step
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 arch = load_tiny("granite_20b")
 model = build(arch, seq_impl="scan")
 opt = make_optimizer("adamw")
@@ -156,7 +159,7 @@ def test_dp_compressed_gradients_subprocess():
     """int8 EF-compressed psum ≈ exact pmean; training still descends."""
     code = DP_COMPRESS_SNIPPET.format(src=os.path.abspath(SRC))
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, timeout=600)
+                         text=True, timeout=600, env=CHILD_ENV)
     assert out.returncode == 0, out.stderr[-2000:]
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert abs(rec["loss_exact"] - rec["loss_comp"]) < 1e-3

@@ -7,22 +7,17 @@ f64, all-masked / single-slot / tie edge cases, mirroring the
 ``test_masked_ops`` contracts) and the two wired engines end to end: a
 differential cell running fleet + power with ``use_pallas="force"``
 asserts every output bit-identical to the plain path — the CPU-only CI
-lane that exercises kernel lowering (interpret mode here; the same
-call lowers natively on TPU/GPU).
+lane that exercises the kernels in interpret mode (a native backend
+refuses these fused routes; see ``test_tpu_compile.py`` for what lowers).
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.vec_engine import StepSpec, body_from_step, x64
 from repro.kernels.ops import masked_argmin, masked_min
-from repro.kernels.step import (StepSpec, body_from_step,
-                                closure_convert_all, fused_scan,
-                                fused_step_body)
-
-
-def _x64():
-    return jax.experimental.enable_x64()
+from repro.kernels.step import closure_convert_all, fused_scan, fused_step_body
 
 
 # -- kernel mechanics: per-step fused body -------------------------------------
@@ -73,7 +68,7 @@ def _toy_init(dtype):
 def test_fused_step_body_bitwise(dtype, mask_mode):
     """One whole iteration as one pallas_call (interpret) must equal the
     jnp body bit-for-bit across dtypes and masked-reduction edge cases."""
-    with _x64():
+    with x64():
         spec = _toy_spec(jnp.dtype(dtype), mask_mode)
         init = _toy_init(jnp.dtype(dtype))
 
@@ -112,7 +107,7 @@ def test_fused_scan_bitwise_vs_fori(dtype):
     carry + per-step blocked streams) must equal lax.fori_loop over the
     same step bit-for-bit — including under jit(vmap(...)), the driver's
     actual dispatch shape."""
-    with _x64():
+    with x64():
         dt = jnp.dtype(dtype)
         T = 9
         rng = np.random.default_rng(3)
@@ -143,7 +138,7 @@ def test_fused_scan_bitwise_vs_fori(dtype):
 
 
 def test_fused_scan_trip_zero_and_short_stream():
-    with _x64():
+    with x64():
         spec = StepSpec(step=lambda s, sl, it: s,
                         streams=dict(x=jnp.zeros((4,))))
         init = (jnp.zeros((2,)),)

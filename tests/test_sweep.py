@@ -103,7 +103,7 @@ print(out["goodput"].tobytes().hex())
     env = dict(os.environ,
                XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
                           + " --xla_force_host_platform_device_count=2"),
-               PYTHONPATH=os.pathsep.join(sys.path))
+               PYTHONPATH=os.pathsep.join(sys.path), JAX_PLATFORMS="cpu")
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr

@@ -136,3 +136,24 @@ def test_every_vec_engine_is_a_substrate_definition():
     assert sorted(e.kind for e in engines) == [
         "cloudlet_batch", "fleet_batch", "netdc_batch", "power_batch",
         "workflow_batch"]
+
+
+def test_plain_path_never_loads_pallas():
+    """use_pallas=False sweeps import no Pallas module: the kernels load
+    only where their route is taken (a fresh process, CPU only)."""
+    import os
+    import subprocess
+    import sys
+    code = ("import sys\n"
+            "from repro.core.backend import run_sweep\n"
+            "run_sweep('power_batch', dict(seeds=[0, 1], n_hosts=4, n_vms=8,"
+            " n_samples=8))\n"
+            "run_sweep('netdc_batch', dict(seeds=[0, 1], n_dcs=3, n_jobs=8))\n"
+            "print(sorted(m for m in sys.modules if 'pallas' in m\n"
+            "             or m == 'repro.kernels.step'))\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(sys.path))
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.strip().splitlines()[-1] == "[]", proc.stdout
