@@ -57,6 +57,7 @@ import numpy as np
 
 from .engine import Simulation
 from .engine_oo import LegacySimulation
+from .spans import report_stats, span, sweep_span, tracing
 
 
 class BackendError(LookupError):
@@ -377,8 +378,31 @@ def run_sweep(kind: str, params: Mapping[str, Any] | None = None, *,
     permissive handler swallowed ``with_report``) — never a bare result
     the caller would mis-unpack.
     """
+    from .sweep import SweepReport
+    with sweep_span() as sweep:
+        scenario_params, config = _sweep_args(kind, params, config, kwargs)
+        with span("sweep.validate"):
+            validate_scenario_params(kind, scenario_params)
+        res = get_backend(backend).run_scenario(
+            kind, with_report=True, **scenario_params, **config.to_kwargs())
+        if not (isinstance(res, tuple) and len(res) == 2
+                and isinstance(res[1], SweepReport)):
+            raise ScenarioUnsupported(
+                f"scenario {kind!r} has no sweep-aware path on backend "
+                f"{backend!r} (handler returned no SweepReport); "
+                f"{_supported_msg(kind)}")
+        if tracing():
+            sweep.set_metadata(**report_stats(res[1]))
+    return ScenarioResult(res[0], res[1], kind=kind,
+                          backend=canonical_name(backend))
+
+
+def _sweep_args(kind: str, params: Mapping[str, Any] | None, config: Any,
+                kwargs: Dict[str, Any]):
+    """``run_sweep``'s arguments as ``(scenario_params, SweepConfig)``: the
+    typed convention checked, the legacy one folded in."""
     global _warned_legacy_controls
-    from .sweep import SweepConfig, SweepReport
+    from .sweep import SweepConfig
     if config is not None and not isinstance(config, SweepConfig):
         raise TypeError(
             f"config must be a SweepConfig, got {type(config).__name__}; "
@@ -428,19 +452,9 @@ def run_sweep(kind: str, params: Mapping[str, Any] | None = None, *,
                     "passing sweep controls as loose run_sweep kwargs "
                     f"({sorted(controls)}) is deprecated — use "
                     "run_sweep(kind, params, config=SweepConfig(...))",
-                    DeprecationWarning, stacklevel=2)
+                    DeprecationWarning, stacklevel=3)
             config = SweepConfig.from_kwargs(**controls)
         scenario_params = kwargs
     if config is None:
         config = SweepConfig()
-    validate_scenario_params(kind, scenario_params)
-    res = get_backend(backend).run_scenario(
-        kind, with_report=True, **scenario_params, **config.to_kwargs())
-    if not (isinstance(res, tuple) and len(res) == 2
-            and isinstance(res[1], SweepReport)):
-        raise ScenarioUnsupported(
-            f"scenario {kind!r} has no sweep-aware path on backend "
-            f"{backend!r} (handler returned no SweepReport); "
-            f"{_supported_msg(kind)}")
-    return ScenarioResult(res[0], res[1], kind=kind,
-                          backend=canonical_name(backend))
+    return scenario_params, config

@@ -62,6 +62,8 @@ from typing import Any, Callable, Dict, Optional, Sequence
 
 import numpy as np
 
+from .spans import span
+
 # jax is imported lazily inside the executors: ``repro.core`` re-exports
 # :class:`SweepReport`, and importing the core package must stay light
 # (the substrate contract — vec engines themselves load lazily too).
@@ -116,6 +118,15 @@ class SweepReport:
     quarantined: int = 0
     retried_segments: int = 0
     quarantined_cells: Optional[np.ndarray] = None
+    # Bytes of the host (numpy) arrays handed to the sweep's executable
+    # calls, summed: what the sweep copied host to device.
+    h2d_bytes: int = 0
+
+    @property
+    def dispatches(self) -> int:
+        """Executable calls the sweep made: its chunks or segments, and
+        the segments a quarantine retried."""
+        return self.n_chunks + self.retried_segments
 
     @property
     def active_lane_fraction_observed(self) -> Optional[float]:
@@ -138,6 +149,7 @@ class SweepReport:
             retires=self.retires, segments=self.segments,
             peak_lanes=self.peak_lanes, quarantined=self.quarantined,
             retried_segments=self.retried_segments,
+            h2d_bytes=self.h2d_bytes,
             observed_active_lane_fraction=(
                 round(self.active_lane_fraction_observed, 4)
                 if self.active_lane_fraction_observed is not None else None),
@@ -327,8 +339,17 @@ def _executor(fn: Callable, devices: tuple, donate: bool,
 def _take(params, idx: np.ndarray):
     """Gather cells ``idx`` along every leaf's leading axis (host side)."""
     import jax
-    return jax.tree_util.tree_map(
-        lambda leaf: np.take(np.asarray(leaf), idx, axis=0), params)
+    with span("sweep.stage"):
+        return jax.tree_util.tree_map(
+            lambda leaf: np.take(np.asarray(leaf), idx, axis=0), params)
+
+
+def _host_bytes(*trees) -> int:
+    """Bytes of the host (numpy) leaves of ``trees``: what a dispatch of
+    them copies to the device (device-resident leaves copy nothing)."""
+    import jax
+    return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(trees)
+               if isinstance(leaf, np.ndarray))
 
 
 def _dispatch(executor, chunk_params, n_devices: int, fold: bool = True):
@@ -342,10 +363,15 @@ def _dispatch(executor, chunk_params, n_devices: int, fold: bool = True):
         def _fold(leaf):
             per = leaf.shape[0] // n_devices
             return leaf.reshape((n_devices, per) + leaf.shape[1:])
-        out = executor(jax.tree_util.tree_map(_fold, chunk_params))
-        return {k: np.asarray(v).reshape((-1,) + np.asarray(v).shape[2:])
-                for k, v in out.items()}
-    return {k: np.asarray(v) for k, v in executor(chunk_params).items()}
+        with span("sweep.dispatch"):
+            out = executor(jax.tree_util.tree_map(_fold, chunk_params))
+        with span("sweep.wait"):
+            return {k: np.asarray(v).reshape((-1,) + np.asarray(v).shape[2:])
+                    for k, v in out.items()}
+    with span("sweep.dispatch"):
+        out = executor(chunk_params)
+    with span("sweep.wait"):
+        return {k: np.asarray(v) for k, v in out.items()}
 
 
 def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
@@ -417,6 +443,7 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
     fold = sharding != "shard_map"
     executor = _executor(fn, devs, donate, sharding)
     chunks, chunk_meta = [], []
+    h2d = 0
     with warnings.catch_warnings():
         if donate:
             warnings.filterwarnings("ignore", message=_DONATION_MSG.pattern)
@@ -426,7 +453,9 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
             if real < chunk_size:                    # pad: repeat final cell
                 idx = np.concatenate(
                     [idx, np.full(chunk_size - real, idx[-1], idx.dtype)])
-            out = _dispatch(executor, _take(params, idx), n_dev, fold)
+            chunk_params = _take(params, idx)
+            h2d += _host_bytes(chunk_params)
+            out = _dispatch(executor, chunk_params, n_dev, fold)
             chunks.append({k: v[:real] for k, v in out.items()})
             chunk_meta.append(real)
             if on_chunk is not None:
@@ -466,7 +495,7 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
         active_lane_fraction_monolithic=frac_mono,
         lane_iterations=lane_iters,
         active_lane_fraction_predicted=frac_pred,
-        sharding=sharding if n_dev > 1 else None)
+        sharding=sharding if n_dev > 1 else None, h2d_bytes=h2d)
     return outputs, report
 
 
@@ -557,27 +586,34 @@ def compact_sweep(step: Callable, params: Any, *,
             slot_cell[s] = slot_cell[0]
     peak_lanes = int(alive.sum())
 
-    params_np = tree.tree_map(np.asarray, params)
-    lane_params = tree.tree_map(lambda l: np.take(l, slot_cell, axis=0),
-                                params_np)
-    lane_leaves = tree.tree_leaves(lane_params)
-    src_leaves = tree.tree_leaves(params_np)
-    state = tree.tree_map(
-        lambda sd: np.zeros((L,) + tuple(sd.shape), sd.dtype),
-        state_prototype)
-    it = np.zeros(L, np.int32)
-    fresh = np.ones(L, bool)
+    with span("sweep.stage"):
+        params_np = tree.tree_map(np.asarray, params)
+        lane_params = tree.tree_map(lambda l: np.take(l, slot_cell, axis=0),
+                                    params_np)
+        lane_leaves = tree.tree_leaves(lane_params)
+        src_leaves = tree.tree_leaves(params_np)
+        state = tree.tree_map(
+            lambda sd: np.zeros((L,) + tuple(sd.shape), sd.dtype),
+            state_prototype)
+        it = np.zeros(L, np.int32)
+        fresh = np.ones(L, bool)
+
+    def dispatch():
+        nonlocal h2d
+        h2d += _host_bytes(lane_params, state, it, fresh)
+        with span("sweep.dispatch"):
+            return step(lane_params, state, it, fresh)
 
     outputs: Optional[Dict[str, np.ndarray]] = None
     lane_iters = np.zeros(n_cells, np.int64)
-    segments = refills = retires = executed = retried = 0
+    segments = refills = retires = executed = retried = h2d = 0
     quarantined_cells: list = []
     with warnings.catch_warnings():
         if donated:
             warnings.filterwarnings("ignore", message=_DONATION_MSG.pattern)
         while alive.any():
             try:
-                state, it, done, j, out = step(lane_params, state, it, fresh)
+                state, it, done, j, out = dispatch()
             except Exception:
                 if not quarantine:
                     raise
@@ -586,12 +622,13 @@ def compact_sweep(step: Callable, params: Any, *,
                 # the failed dispatch consumed are re-creatable: retry the
                 # segment once before letting the error kill the run.
                 retried += 1
-                state, it, done, j, out = step(lane_params, state, it, fresh)
-            if quarantine:
-                state = tree.tree_map(np.asarray, state)
-                it = np.asarray(it)
-            done_np = np.asarray(done)
-            j_max = int(np.asarray(j).max())
+                state, it, done, j, out = dispatch()
+            with span("sweep.wait"):
+                if quarantine:
+                    state = tree.tree_map(np.asarray, state)
+                    it = np.asarray(it)
+                done_np = np.asarray(done)
+                j_max = int(np.asarray(j).max())
             segments += 1
             executed += L * j_max
             quar = np.zeros(L, bool)
@@ -612,39 +649,44 @@ def compact_sweep(step: Callable, params: Any, *,
             newly = done_np & alive & ~quar
             fresh = np.zeros(L, bool)
             if newly.any() or quar.any():
-                out_np = {k: np.asarray(v) for k, v in out.items()}
-                if outputs is None:
-                    outputs = {
-                        k: np.zeros((n_cells,) + v.shape[1:], v.dtype)
-                        for k, v in out_np.items()}
-                if newly.any():
-                    cells = slot_cell[newly]
-                    for k, v in out_np.items():
-                        outputs[k][cells] = v[newly]
-                    if iterations_key in out_np:
-                        lane_iters[cells] = np.asarray(
-                            out_np[iterations_key][newly], np.int64)
-                    retires += len(cells)
-                    if on_chunk is not None:
-                        on_chunk(cells.copy(),
-                                 {k: v[newly].copy()
-                                  for k, v in out_np.items()})
-                if quar.any():
-                    q_cells = slot_cell[quar]
-                    quarantined_cells.extend(int(c) for c in q_cells)
-                    for v in outputs.values():
-                        if np.issubdtype(v.dtype, np.floating):
-                            v[q_cells] = np.nan
-                for s in np.flatnonzero(newly | quar):
-                    if queue:
-                        c = queue.popleft()
-                        slot_cell[s] = c
+                with span("sweep.wait"):
+                    out_np = {k: np.asarray(v) for k, v in out.items()}
+                with span("sweep.retire"):
+                    if outputs is None:
+                        outputs = {
+                            k: np.zeros((n_cells,) + v.shape[1:], v.dtype)
+                            for k, v in out_np.items()}
+                    if newly.any():
+                        cells = slot_cell[newly]
+                        for k, v in out_np.items():
+                            outputs[k][cells] = v[newly]
+                        if iterations_key in out_np:
+                            lane_iters[cells] = np.asarray(
+                                out_np[iterations_key][newly], np.int64)
+                        retires += len(cells)
+                        if on_chunk is not None:
+                            on_chunk(cells.copy(),
+                                     {k: v[newly].copy()
+                                      for k, v in out_np.items()})
+                    if quar.any():
+                        q_cells = slot_cell[quar]
+                        quarantined_cells.extend(int(c) for c in q_cells)
+                        for v in outputs.values():
+                            if np.issubdtype(v.dtype, np.floating):
+                                v[q_cells] = np.nan
+                    # Freed slots take the next queued cells in slot order;
+                    # the rest go idle.
+                    freed = np.flatnonzero(newly | quar)
+                    n_new = min(len(freed), len(queue))
+                    slots = freed[:n_new]
+                    alive[freed[n_new:]] = False
+                    slot_cell[slots] = [queue.popleft() for _ in range(n_new)]
+                    fresh[slots] = True
+                    refills += n_new
+                if n_new:
+                    with span("sweep.stage"):
                         for lp, src in zip(lane_leaves, src_leaves):
-                            lp[s] = src[c]
-                        fresh[s] = True
-                        refills += 1
-                    else:
-                        alive[s] = False
+                            lp[slots] = src[slot_cell[slots]]
             elif j_max == 0:
                 raise RuntimeError(
                     "compact_sweep: no lane progressed and none finished — "
@@ -670,7 +712,8 @@ def compact_sweep(step: Callable, params: Any, *,
         segments=segments, peak_lanes=peak_lanes,
         quarantined=len(quarantined_cells), retried_segments=retried,
         quarantined_cells=(np.asarray(quarantined_cells, np.int64)
-                           if quarantined_cells else None))
+                           if quarantined_cells else None),
+        h2d_bytes=h2d)
     return outputs, report
 
 

@@ -65,6 +65,7 @@ import numpy as np
 
 from ..kernels.ops import MaskedOps, pallas_native, resolve_use_pallas
 from .backend import scenario
+from .spans import span
 from .sweep import (MIN_CHUNK, SweepReport, compact_sweep, execute_sweep,
                     resolve_devices)
 
@@ -223,8 +224,10 @@ def run_one(engine: VecEngine, params: Any, statics: Any) -> Dict[str, Any]:
 def batched_sim(engine: VecEngine, statics: Any) -> Callable:
     """Batched (vmap) simulator for one static shape, in the sweep layer's
     single-pytree calling convention — cached so the sweep executor (which
-    jits with buffer donation) reuses one compiled executable per shape."""
-    return jax.vmap(functools.partial(run_one, engine, statics=statics))
+    jits with buffer donation) reuses one compiled executable per shape.
+    Its ops carry the scope ``<kind>/loop`` in the profiler's trace."""
+    one = functools.partial(run_one, engine, statics=statics)
+    return jax.vmap(jax.named_scope(engine.kind)(jax.named_scope("loop")(one)))
 
 
 # -- compacting-scheduler segment step -----------------------------------------
@@ -252,10 +255,13 @@ def _segment_sim(engine: VecEngine, statics: Any, budget: int) -> Callable:
     cannot express, and the per-step fused body buys nothing under the
     segment budget's extra select masking.  ``use_pallas`` still routes
     the *reductions* through the next-event kernel here; outputs stay
-    bit-identical to the monolithic (fused or not) run either way.
+    bit-identical to the monolithic (fused or not) run either way.  Its
+    ops carry the scope ``<kind>/segment`` in the profiler's trace.
     """
     ops = MaskedOps(bool(getattr(statics, "use_pallas", False)))
 
+    @jax.named_scope(engine.kind)
+    @jax.named_scope("segment")
     def seg_one(params, state, it, fresh):
         loop = engine.build(params, statics, ops)
         # A fresh lane adopts its new cell's initial state; a resident lane
@@ -430,10 +436,11 @@ def run_compact(engine: VecEngine, plan: BatchPlan, *, chunk_size=None,
     def step(lane_params, state, it, fresh):
         return step5(lane_params, state, it, fresh, sid_arr)
 
+    with span("sweep.stage"):
+        prototype = state_prototype(engine, statics, params)
     try:
         return compact_sweep(
-            step, params, lanes=lanes,
-            state_prototype=state_prototype(engine, statics, params),
+            step, params, lanes=lanes, state_prototype=prototype,
             n_devices=len(devs), predicted_cost=plan.predicted_cost,
             on_chunk=on_chunk, donated=donate, quarantine=quarantine)
     finally:
@@ -477,7 +484,8 @@ def run_plan(engine: VecEngine, plan, *, chunk_size=None, devices=None,
                     predicted_cost=plan.predicted_cost,
                     sharding=sharding or "pmap", on_chunk=on_chunk)
         if plan.finalize is not None:
-            out = plan.finalize(out)
+            with span("sweep.finalize"):
+                out = plan.finalize(out)
     return (out, report) if with_report else out
 
 
@@ -507,7 +515,9 @@ def make_batch_entry(engine: VecEngine, prepare: Callable, *,
               progress: Optional[Callable] = None,
               quarantine: bool = False,
               **kw):
-        plan = prepare(*args, use_pallas=resolve_use_pallas(use_pallas), **kw)
+        with span("sweep.prepare"):
+            plan = prepare(*args, use_pallas=resolve_use_pallas(use_pallas),
+                           **kw)
         return run_plan(engine, plan, chunk_size=chunk_size, devices=devices,
                         donate=donate, with_report=with_report,
                         compact=compact, segment_iters=segment_iters,

@@ -30,6 +30,7 @@ import numpy as np
 from . import f64bits
 from .faults import FaultPlan, RetryPolicy
 from .llmserve import build_cells, empty_llmserve_outputs, summarize
+from .spans import span
 from .vec_engine import BatchPlan, Done, Loop, VecEngine, make_batch_entry
 
 
@@ -182,27 +183,30 @@ def _prepare_llmserve(*, use_pallas: bool, seeds=(0,), n_machines: int = 6,
                       fault_plan: Optional[FaultPlan] = None,
                       retry: Optional[RetryPolicy] = None,
                       timeout_s: float = math.inf, workload=None):
-    cells, b = build_cells(
-        seeds=seeds, n_machines=n_machines, n_regions=n_regions,
-        n_stages=n_stages, n_pipelines=n_pipelines, n_layers=n_layers,
-        n_requests=n_requests, placement=placement, machines=machines,
-        mean_gap_s=mean_gap_s, locality_weight=locality_weight,
-        offline_region=offline_region, offline_frac=offline_frac,
-        slo_ttft_s=slo_ttft_s, kv_penalty_s=kv_penalty_s, link_bw=link_bw,
-        hop_latency_s=hop_latency_s, prompt_tokens=prompt_tokens,
-        decode_tokens=decode_tokens, fault_plan=fault_plan, retry=retry,
-        timeout_s=timeout_s, workload=workload)
+    with span("sweep.prepare.build"):
+        cells, b = build_cells(
+            seeds=seeds, n_machines=n_machines, n_regions=n_regions,
+            n_stages=n_stages, n_pipelines=n_pipelines, n_layers=n_layers,
+            n_requests=n_requests, placement=placement, machines=machines,
+            mean_gap_s=mean_gap_s, locality_weight=locality_weight,
+            offline_region=offline_region, offline_frac=offline_frac,
+            slo_ttft_s=slo_ttft_s, kv_penalty_s=kv_penalty_s,
+            link_bw=link_bw, hop_latency_s=hop_latency_s,
+            prompt_tokens=prompt_tokens, decode_tokens=decode_tokens,
+            fault_plan=fault_plan, retry=retry, timeout_s=timeout_s,
+            workload=workload)
     if b == 0:
         return Done(empty_llmserve_outputs(
             int(n_machines), faulted=fault_plan is not None
             or math.isfinite(timeout_s)))
     fx = cells[0].fx
-    packed = _pack_cells(cells)
     exact_bits = not f64bits.native()
-    if exact_bits:
-        kv_need = packed[..., -1].astype(np.int64)
-        packed = f64bits.bits(packed)
-        packed[..., -1] = kv_need
+    with span("sweep.prepare.pack"):
+        packed = _pack_cells(cells)
+        if exact_bits:
+            kv_need = packed[..., -1].astype(np.int64)
+            packed = f64bits.bits(packed)
+            packed[..., -1] = kv_need
     params = _Params(packed=packed)
     n_pipes, n_st = cells[0].placement.shape
     n_requests = len(cells[0].submit)  # an injected workload sets its own

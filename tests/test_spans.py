@@ -1,0 +1,151 @@
+"""The sweep path's host spans and counters in the profiler's trace: every
+span nested in one ``sweep`` span per ``run_sweep`` call, the sweep's
+counters on it, and nothing changed with the profiler off."""
+import glob
+import os
+import re
+
+import jax
+import numpy as np
+import pytest
+
+from bench import programspans
+from repro.core import backend, spans, vec_engine
+from repro.core.backend import run_sweep
+from repro.core.sweep import SweepConfig
+from repro.core.vec_llmserve import LLMSERVE_ENGINE, _prepare_llmserve
+
+KIND = "llmserve_batch"
+PARAMS = dict(seeds=np.arange(8), n_requests=40)
+CONFIGS = {"compact": SweepConfig(compact=True, chunk_size=4,
+                                  segment_iters=16),
+           "chunked": SweepConfig(chunk_size=4)}
+NAMES = {"sweep", "sweep.validate", "sweep.prepare", "sweep.prepare.build",
+         "sweep.prepare.pack", "sweep.stage", "sweep.dispatch", "sweep.wait",
+         "sweep.finalize"}
+
+
+def _traced(directory, config):
+    run_sweep(KIND, PARAMS, config=config)          # compiles untraced
+    with jax.profiler.trace(str(directory)):
+        res = run_sweep(KIND, PARAMS, config=config)
+    path = max(glob.glob(os.path.join(str(directory), "**", "*.xplane.pb"),
+                         recursive=True))
+    return res, programspans.load_program(path)
+
+
+@pytest.fixture(scope="module", params=sorted(CONFIGS))
+def traced(request, tmp_path_factory):
+    res, found = _traced(tmp_path_factory.mktemp(request.param),
+                         CONFIGS[request.param])
+    return request.param, res, found
+
+
+def test_every_span_nests_under_one_sweep(traced):
+    path, _, found = traced
+    sweeps = [p for p in found if p[0] == "sweep"]
+    assert len(sweeps) == 1
+    _, lo, hi, _ = sweeps[0]
+    assert all(lo <= s and e <= hi for _, s, e, _ in found)
+    want = NAMES | ({"sweep.retire"} if path == "compact" else set())
+    assert {p[0] for p in found} == want
+    (_, plo, phi, _), = [p for p in found if p[0] == "sweep.prepare"]
+    for child in ("sweep.prepare.build", "sweep.prepare.pack"):
+        (_, s, e, _), = [p for p in found if p[0] == child]
+        assert plo <= s and e <= phi
+
+
+def test_sweep_span_carries_the_report(traced):
+    path, res, found = traced
+    (_, _, _, stats), = [p for p in found if p[0] == "sweep"]
+    rep = res.report
+    assert stats["dispatches"] == (rep.segments if path == "compact"
+                                   else rep.n_chunks)
+    assert stats["dispatches"] == rep.dispatches > 1
+    assert stats["id"] >= 1
+    for k, v in rep.report_fields().items():
+        if v is None:
+            assert k not in stats
+        elif not isinstance(v, str):
+            assert stats[k] == v, k
+    n_dispatch = sum(p[0] == "sweep.dispatch" for p in found)
+    assert n_dispatch == rep.dispatches
+    assert sum(p[0] == "sweep.wait" for p in found) >= n_dispatch
+    # No span per cell or per lane: a few per dispatch and a fixed few more.
+    assert len(found) <= 3 * rep.dispatches + 8
+
+
+def test_h2d_bytes_from_shapes(traced):
+    path, res, found = traced
+    plan = _prepare_llmserve(use_pallas=False, **PARAMS)
+    b, j, k = plan.params.packed.shape
+    itemsize = plan.params.packed.dtype.itemsize
+    rep = res.report
+    lanes = 4
+    lane_params = lanes * j * k * itemsize
+    if path == "compact":
+        with vec_engine.x64():
+            proto = vec_engine.state_prototype(LLMSERVE_ENGINE, plan.statics,
+                                               plan.params)
+        state = sum(lanes * int(np.prod(sd.shape)) * sd.dtype.itemsize
+                    for sd in jax.tree_util.tree_leaves(proto))
+        # Every segment sends the lane params and the fresh mask; the first
+        # also the zeroed state and iteration counters, which stay on the
+        # device after.
+        want = rep.segments * (lane_params + lanes) + state + 4 * lanes
+    else:
+        want = rep.n_chunks * lane_params
+    assert rep.h2d_bytes == want
+    (_, _, _, stats), = [p for p in found if p[0] == "sweep"]
+    assert stats["h2d_bytes"] == want
+
+
+@pytest.mark.parametrize("path", sorted(CONFIGS))
+def test_profiler_off_changes_nothing(path, tmp_path, monkeypatch):
+    config = CONFIGS[path]
+    traced, _ = _traced(tmp_path, config)
+    assert not spans.tracing()
+
+    def no_stats(report):
+        raise AssertionError("stats built with the profiler off")
+    monkeypatch.setattr(backend, "report_stats", no_stats)
+    plain = run_sweep(KIND, PARAMS, config=config)
+    assert plain.outputs.keys() == traced.outputs.keys()
+    for key, v in plain.outputs.items():
+        v, w = np.asarray(v), np.asarray(traced.outputs[key])
+        assert v.dtype == w.dtype and v.tobytes() == w.tobytes(), key
+    assert plain.report_fields() == traced.report_fields()
+
+
+def test_each_call_has_its_own_sweep_id(tmp_path):
+    config = CONFIGS["chunked"]
+    run_sweep(KIND, PARAMS, config=config)
+    with jax.profiler.trace(str(tmp_path)):
+        run_sweep(KIND, PARAMS, config=config)
+        run_sweep(KIND, PARAMS, config=config)
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    found = programspans.load_program(path)
+    first, second = [p for p in found if p[0] == "sweep"]
+    assert second[3]["id"] == first[3]["id"] + 1
+    for _, s, e, _ in found:
+        assert any(lo <= s and e <= hi for _, lo, hi, _ in (first, second))
+
+
+def test_device_ops_carry_the_engine_scope():
+    plan = _prepare_llmserve(use_pallas=False, seeds=np.arange(2),
+                             n_requests=8)
+    with vec_engine.x64():
+        loop = jax.jit(vec_engine.batched_sim(LLMSERVE_ENGINE, plan.statics))
+        hlo = loop.lower(plan.params).compile().as_text()
+        seg = jax.jit(vec_engine._segment_sim(LLMSERVE_ENGINE, plan.statics,
+                                              4))
+        proto = vec_engine.state_prototype(LLMSERVE_ENGINE, plan.statics,
+                                           plan.params)
+        state = jax.tree_util.tree_map(
+            lambda sd: np.zeros((2,) + sd.shape, sd.dtype), proto)
+        seg_hlo = seg.lower(plan.params, state, np.zeros(2, np.int32),
+                            np.ones(2, bool)).compile().as_text()
+    assert re.search(r'op_name="[^"]*llmserve_batch\)?/loop/while', hlo)
+    assert re.search(r'op_name="[^"]*llmserve_batch\)?/segment/while',
+                     seg_hlo)
