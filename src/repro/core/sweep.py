@@ -119,8 +119,13 @@ class SweepReport:
     retried_segments: int = 0
     quarantined_cells: Optional[np.ndarray] = None
     # Bytes of the host (numpy) arrays handed to the sweep's executable
-    # calls, summed: what the sweep copied host to device.
+    # calls or put on the device for them, summed: what the sweep copied
+    # host to device.
     h2d_bytes: int = 0
+    # Copies of the lane-params pytree from host to device: one per chunk
+    # on the chunked path; on the compacting path one, plus one for each
+    # segment that follows a refill.
+    param_uploads: int = 0
 
     @property
     def dispatches(self) -> int:
@@ -149,7 +154,7 @@ class SweepReport:
             retires=self.retires, segments=self.segments,
             peak_lanes=self.peak_lanes, quarantined=self.quarantined,
             retried_segments=self.retried_segments,
-            h2d_bytes=self.h2d_bytes,
+            h2d_bytes=self.h2d_bytes, param_uploads=self.param_uploads,
             observed_active_lane_fraction=(
                 round(self.active_lane_fraction_observed, 4)
                 if self.active_lane_fraction_observed is not None else None),
@@ -300,6 +305,15 @@ def auto_chunk_size(n_cells: int, predicted_cost, n_devices: int) -> int:
     return n_cells if chunk >= n_cells else chunk
 
 
+def lanes_sharding(devices: Sequence[Any]):
+    """The lane axis split over ``devices``: a 1-D ``lanes`` mesh, the
+    placement every multi-device ``shard_map`` executor takes its lane
+    arguments in."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    return NamedSharding(Mesh(np.array(list(devices)), ("lanes",)),
+                         PartitionSpec("lanes"))
+
+
 @functools.lru_cache(maxsize=64)
 def _executor(fn: Callable, devices: tuple, donate: bool,
               sharding: str = "pmap") -> Callable:
@@ -318,12 +332,10 @@ def _executor(fn: Callable, devices: tuple, donate: bool,
     donate_argnums = (0,) if donate else ()
     if len(devices) > 1:
         if sharding == "shard_map":
-            from jax.sharding import Mesh, PartitionSpec
-            mesh = Mesh(np.array(list(devices)), ("lanes",))
-            spec = PartitionSpec("lanes")
+            sh = lanes_sharding(devices)
             # check_vma=False: lanes are independent, nothing is replicated.
-            lanes = jax.shard_map(fn, mesh=mesh, in_specs=(spec,),
-                                  out_specs=spec, check_vma=False)
+            lanes = jax.shard_map(fn, mesh=sh.mesh, in_specs=(sh.spec,),
+                                  out_specs=sh.spec, check_vma=False)
             return jax.jit(lanes, donate_argnums=donate_argnums)
         return jax.pmap(fn, devices=list(devices),
                         donate_argnums=donate_argnums)
@@ -443,7 +455,7 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
     fold = sharding != "shard_map"
     executor = _executor(fn, devs, donate, sharding)
     chunks, chunk_meta = [], []
-    h2d = 0
+    h2d = uploads = 0
     with warnings.catch_warnings():
         if donate:
             warnings.filterwarnings("ignore", message=_DONATION_MSG.pattern)
@@ -453,8 +465,15 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
             if real < chunk_size:                    # pad: repeat final cell
                 idx = np.concatenate(
                     [idx, np.full(chunk_size - real, idx[-1], idx.dtype)])
-            chunk_params = _take(params, idx)
+            if chunk_size == n_cells:
+                # One chunk of every cell in order: the gather would be a
+                # copy of ``params``.  The caller's host arrays are handed
+                # over as they are; donation consumes only the device copy.
+                chunk_params = jax.tree_util.tree_map(np.asarray, params)
+            else:
+                chunk_params = _take(params, idx)
             h2d += _host_bytes(chunk_params)
+            uploads += 1
             out = _dispatch(executor, chunk_params, n_dev, fold)
             chunks.append({k: v[:real] for k, v in out.items()})
             chunk_meta.append(real)
@@ -495,14 +514,15 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
         active_lane_fraction_monolithic=frac_mono,
         lane_iterations=lane_iters,
         active_lane_fraction_predicted=frac_pred,
-        sharding=sharding if n_dev > 1 else None, h2d_bytes=h2d)
+        sharding=sharding if n_dev > 1 else None, h2d_bytes=h2d,
+        param_uploads=uploads)
     return outputs, report
 
 
 def compact_sweep(step: Callable, params: Any, *,
                   lanes: int,
                   state_prototype: Any,
-                  n_devices: int = 1,
+                  devices: Sequence[Any] = (),
                   predicted_cost=None,
                   on_chunk: Optional[Callable] = None,
                   iterations_key: str = "iterations",
@@ -529,6 +549,16 @@ def compact_sweep(step: Callable, params: Any, *,
     size, and the compiled batch is always dense: the active-lane fraction
     approaches 1 by construction instead of depending on how well
     ``predicted_cost`` ordered the grid.
+
+    The resident batch's lane params live on the device for the whole
+    sweep: put there once before the first segment (split over the
+    ``lanes`` mesh of ``devices`` when there are several, the placement
+    the sharded step takes), and sent again only for a segment that
+    follows a refill, which rewrites their host mirror.  When the batch
+    holds every cell in order, the mirror is ``params`` itself, not a
+    gathered copy.  ``SweepReport.param_uploads`` counts the copies.
+    ``devices`` are the devices the step's lanes are sharded over; empty
+    or one means the default device.
 
     Because lanes are independent and a retired lane's state/iteration pair
     at its final segment equals the monolithic run's, outputs are
@@ -563,7 +593,8 @@ def compact_sweep(step: Callable, params: Any, *,
     if n_cells == 0:
         raise ValueError("compact_sweep: empty grid — route degenerate "
                          "batches through execute_sweep")
-    n_devices = max(1, min(int(n_devices), n_cells))
+    devices = tuple(devices)[:n_cells]
+    n_devices = max(1, len(devices))
     L = max(1, min(int(lanes), n_cells))
     L = -(-L // n_devices) * n_devices          # shards must split evenly
 
@@ -588,8 +619,14 @@ def compact_sweep(step: Callable, params: Any, *,
 
     with span("sweep.stage"):
         params_np = tree.tree_map(np.asarray, params)
-        lane_params = tree.tree_map(lambda l: np.take(l, slot_cell, axis=0),
-                                    params_np)
+        if np.array_equal(slot_cell, np.arange(n_cells)):
+            # Every cell resident, in order: the queue is empty after this
+            # fill, so no refill ever writes the mirror, and it may alias
+            # the caller's ``params``.
+            lane_params = params_np
+        else:
+            lane_params = tree.tree_map(
+                lambda l: np.take(l, slot_cell, axis=0), params_np)
         lane_leaves = tree.tree_leaves(lane_params)
         src_leaves = tree.tree_leaves(params_np)
         state = tree.tree_map(
@@ -597,16 +634,22 @@ def compact_sweep(step: Callable, params: Any, *,
             state_prototype)
         it = np.zeros(L, np.int32)
         fresh = np.ones(L, bool)
+    placement = lanes_sharding(devices) if n_devices > 1 else None
+    lane_dev = None            # the mirror's device copy; None once stale
 
     def dispatch():
-        nonlocal h2d
-        h2d += _host_bytes(lane_params, state, it, fresh)
+        nonlocal h2d, uploads, lane_dev
         with span("sweep.dispatch"):
-            return step(lane_params, state, it, fresh)
+            if lane_dev is None:
+                h2d += _host_bytes(lane_params)
+                uploads += 1
+                lane_dev = jax.device_put(lane_params, placement)
+            h2d += _host_bytes(state, it, fresh)
+            return step(lane_dev, state, it, fresh)
 
     outputs: Optional[Dict[str, np.ndarray]] = None
     lane_iters = np.zeros(n_cells, np.int64)
-    segments = refills = retires = executed = retried = h2d = 0
+    segments = refills = retires = executed = retried = h2d = uploads = 0
     quarantined_cells: list = []
     with warnings.catch_warnings():
         if donated:
@@ -687,6 +730,7 @@ def compact_sweep(step: Callable, params: Any, *,
                     with span("sweep.stage"):
                         for lp, src in zip(lane_leaves, src_leaves):
                             lp[slots] = src[slot_cell[slots]]
+                    lane_dev = None
             elif j_max == 0:
                 raise RuntimeError(
                     "compact_sweep: no lane progressed and none finished — "
@@ -713,7 +757,7 @@ def compact_sweep(step: Callable, params: Any, *,
         quarantined=len(quarantined_cells), retried_segments=retried,
         quarantined_cells=(np.asarray(quarantined_cells, np.int64)
                            if quarantined_cells else None),
-        h2d_bytes=h2d)
+        h2d_bytes=h2d, param_uploads=uploads)
     return outputs, report
 
 

@@ -67,7 +67,7 @@ from ..kernels.ops import MaskedOps, pallas_native, resolve_use_pallas
 from .backend import scenario
 from .spans import span
 from .sweep import (MIN_CHUNK, SweepReport, compact_sweep, execute_sweep,
-                    resolve_devices)
+                    lanes_sharding, resolve_devices)
 
 
 @contextlib.contextmanager
@@ -311,12 +311,10 @@ def segment_step(engine: VecEngine, statics: Any, budget: int,
     core = _segment_sim(engine, statics, budget)
     donate_argnums = (1, 2) if donate else ()
     if len(devices) > 1:
-        from jax.sharding import Mesh, PartitionSpec
-        mesh = Mesh(np.array(list(devices)), ("lanes",))
-        spec = PartitionSpec("lanes")
+        sh = lanes_sharding(devices)
         # check_vma=False: lanes are independent, nothing is replicated.
-        sharded = jax.shard_map(core, mesh=mesh, in_specs=(spec,) * 4,
-                                out_specs=spec, check_vma=False)
+        sharded = jax.shard_map(core, mesh=sh.mesh, in_specs=(sh.spec,) * 4,
+                                out_specs=sh.spec, check_vma=False)
 
         def stepped(lane_params, state, it, fresh, sink_id):
             del sink_id                # retire tap is single-device only
@@ -441,7 +439,7 @@ def run_compact(engine: VecEngine, plan: BatchPlan, *, chunk_size=None,
     try:
         return compact_sweep(
             step, params, lanes=lanes, state_prototype=prototype,
-            n_devices=len(devs), predicted_cost=plan.predicted_cost,
+            devices=devs, predicted_cost=plan.predicted_cost,
             on_chunk=on_chunk, donated=donate, quarantine=quarantine)
     finally:
         if sid:

@@ -198,6 +198,104 @@ def test_chunked_report_carries_predicted_and_observed_fractions():
     assert not rep.compacted and rep.refills == 0 and rep.segments == 0
 
 
+# -- device-resident lane params ---------------------------------------------
+
+LLM = dict(seeds=np.arange(8), n_requests=40)
+
+
+def test_identity_batch_uploads_params_once(monkeypatch):
+    """Every cell resident in order (no predicted cost, lanes = cells): the
+    caller's params go to the device as they are — no gathered copy — once,
+    and every later segment reuses the device copy."""
+    import jax
+
+    from repro.core import vec_engine
+    mono = run_sweep("llmserve_batch", LLM).outputs
+    handed, put = [], []
+    real_sweep, real_put = vec_engine.compact_sweep, jax.device_put
+
+    def keep(step, params, **kw):
+        handed.append(params)
+        return real_sweep(step, params, **kw)
+
+    def spy(x, *a, **k):
+        put.append(x)
+        return real_put(x, *a, **k)
+    monkeypatch.setattr(vec_engine, "compact_sweep", keep)
+    monkeypatch.setattr(jax, "device_put", spy)
+    out, rep = run_sweep("llmserve_batch", LLM, config=SweepConfig(
+        compact=True, chunk_size=8, segment_iters=16))
+    assert rep.segments > 1 and rep.refills == 0
+    assert rep.param_uploads == 1 and len(put) == 1
+    for sent, given in zip(jax.tree_util.tree_leaves(put[0]),
+                           jax.tree_util.tree_leaves(handed[0])):
+        assert np.shares_memory(sent, np.asarray(given))
+    for k in mono:
+        assert np.array_equal(mono[k], out[k]), k
+
+
+def test_refill_reuploads_params_after_refills_only(mono, monkeypatch):
+    """A refilling sweep sends the lane params once, then once more for each
+    segment that follows a refill (its fresh mask names new lanes), and
+    stays bit-identical to the monolithic dispatch."""
+    from repro.core import vec_engine
+    fresh, real = [], vec_engine.segment_step
+
+    def spying(*a, **k):
+        step5 = real(*a, **k)
+
+        def step(lane_params, state, it, f, sid):
+            fresh.append(np.array(f))
+            return step5(lane_params, state, it, f, sid)
+        return step
+    monkeypatch.setattr(vec_engine, "segment_step", spying)
+    out, rep = _fleet(compact=True, chunk_size=8, segment_iters=7,
+                      with_report=True)
+    assert len(fresh) == rep.segments and fresh[0].all()
+    after_refill = sum(bool(f.any()) for f in fresh[1:])
+    assert rep.refills == B - 8 and 0 < after_refill < rep.segments - 1
+    assert rep.param_uploads == 1 + after_refill
+    for k in mono:
+        assert np.array_equal(mono[k], out[k]), k
+
+
+def _jit_counting_step(budget=4):
+    """Jitted segment step, state and counters donated as the engines' are:
+    lane i runs ``need[i]`` iterations adding ``v[i]`` each."""
+    import jax
+    import jax.numpy as jnp
+
+    def step(lane_params, state, it, fresh):
+        v, need = lane_params
+        state = jnp.where(fresh, 0.0, state)
+        it = jnp.where(fresh, 0, it)
+        j = jnp.clip(need - it, 0, budget)
+        state, it = state + v * j, it + j
+        return state, it, it >= need, j, {"total": state, "iterations": it}
+    return jax.jit(step, donate_argnums=(1, 2))
+
+
+@pytest.mark.parametrize("quarantine", [False, True])
+def test_identity_compact_leaves_caller_params_unchanged(quarantine):
+    """The resident batch aliases the caller's params on the identity path:
+    a donated sweep, and a quarantine that frees a slot, write nothing into
+    them."""
+    from repro.core.sweep import compact_sweep
+    v = np.arange(1, 9, dtype=np.float32)
+    if quarantine:
+        v[5] = np.nan
+    need = np.arange(4, 36, 4, dtype=np.int32)
+    before = (v.tobytes(), need.tobytes())
+    out, rep = compact_sweep(_jit_counting_step(), (v, need), lanes=8,
+                             state_prototype=np.zeros((), np.float32),
+                             quarantine=quarantine)
+    assert (v.tobytes(), need.tobytes()) == before
+    assert rep.param_uploads == 1 and rep.segments == 8
+    assert rep.quarantined == int(quarantine)
+    ok = ~np.isnan(v)
+    assert np.array_equal(out["total"][ok], (v * need)[ok])
+
+
 # -- sharding ------------------------------------------------------------------
 
 def test_execute_sweep_rejects_unknown_sharding():
@@ -250,6 +348,15 @@ cout, crep = simulate_fleet_batch(cost, cfg, 60, compact=True,
                                   with_report=True, **kw)
 assert crep.devices == 2 and crep.sharding == "shard_map", crep
 assert crep.compacted and crep.refills > 0, crep
+assert 2 <= crep.param_uploads < crep.segments, crep
+# The lane params are put on the device split as the sharded step takes
+# them: a reshard in any segment would be a device-to-device copy.
+with jax.transfer_guard_device_to_device("disallow"):
+    gout, grep = simulate_fleet_batch(cost, cfg, 60, compact=True,
+                                      chunk_size=8, segment_iters=7,
+                                      with_report=True, **kw)
+assert grep.report_fields() == crep.report_fields()
+assert all(np.array_equal(gout[k], cout[k]) for k in cout)
 print(cout["wallclock_s"].tobytes().hex())
 print(cout["goodput"].tobytes().hex())
 """)

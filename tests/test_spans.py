@@ -89,11 +89,16 @@ def test_h2d_bytes_from_shapes(traced):
                                                plan.params)
         state = sum(lanes * int(np.prod(sd.shape)) * sd.dtype.itemsize
                     for sd in jax.tree_util.tree_leaves(proto))
-        # Every segment sends the lane params and the fresh mask; the first
-        # also the zeroed state and iteration counters, which stay on the
-        # device after.
-        want = rep.segments * (lane_params + lanes) + state + 4 * lanes
+        # The lane params go to the device once, and again for each segment
+        # after a refill (8 cells on 4 lanes do refill); every segment sends
+        # the fresh mask; the first also the zeroed state and iteration
+        # counters, which stay on the device after.
+        assert rep.refills > 0
+        assert 2 <= rep.param_uploads <= rep.segments
+        want = (rep.param_uploads * lane_params + rep.segments * lanes
+                + state + 4 * lanes)
     else:
+        assert rep.param_uploads == rep.n_chunks
         want = rep.n_chunks * lane_params
     assert rep.h2d_bytes == want
     (_, _, _, stats), = [p for p in found if p[0] == "sweep"]

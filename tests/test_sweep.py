@@ -338,6 +338,40 @@ def test_identical_lanes_bucketing_degenerate():
     assert np.array_equal(out["y"], chunked["y"])
 
 
+def _aliasing_fn(params):
+    """Output of the input's shape and dtype, so a donated input buffer
+    can be reused for it."""
+    (x,) = params
+    import jax.numpy as jnp
+    return {"y": x * 3 + 1, "iterations": jnp.ones(x.shape[0], jnp.int32)}
+
+
+@pytest.mark.parametrize("chunk_size,identity", [(48, True), (20, False)])
+def test_chunk_params_gathered_only_when_cells_move(chunk_size, identity,
+                                                    monkeypatch):
+    """One chunk of every cell in order hands the caller's arrays to the
+    dispatch as they are; chunks that cut, pad or reorder the cells get a
+    gathered copy.  One params upload per chunk either way, and a donated
+    dispatch leaves the caller's params byte for byte as they were."""
+    from repro.core import sweep
+    x = np.arange(48 * 16, dtype=np.int32).reshape(48, 16)
+    before = x.tobytes()
+    handed, real = [], sweep._dispatch
+
+    def spy(executor, chunk_params, *a):
+        handed.append(chunk_params[0])
+        return real(executor, chunk_params, *a)
+    monkeypatch.setattr(sweep, "_dispatch", spy)
+    out, rep = sweep.execute_sweep(_aliasing_fn, (x,), chunk_size=chunk_size,
+                                   donate=True)
+    assert x.tobytes() == before
+    assert np.array_equal(out["y"], x * 3 + 1)
+    assert rep.param_uploads == rep.n_chunks == len(handed)
+    assert rep.h2d_bytes == rep.n_chunks * rep.chunk_size * 16 * 4
+    assert [np.shares_memory(h, x) for h in handed] == \
+        [identity] * rep.n_chunks
+
+
 def test_run_sweep_rejection_messages():
     """Unregistered kind/backend pairs reject with an actionable message —
     naming the kind, the backend, and where the scenario IS available."""
