@@ -290,7 +290,7 @@ class ScenarioResult(tuple):
 # (division by zero, instant-failure storms) only *after* a sweep compiles
 # and dispatches; rejecting at entry names the axis and index instead.
 _POSITIVE_PARAMS = frozenset({
-    "mean_gap_s", "link_bw", "dc_mips", "host_mips", "vm_mips",
+    "mean_gap_s", "link_bw", "dc_mips", "host_mips", "host_pes", "vm_mips",
     "guest_mips", "mtbf_hours", "mtbf_hours_node", "degrade_mtbf_hours",
     "interval", "total_steps", "n_samples",
 })

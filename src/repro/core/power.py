@@ -507,6 +507,24 @@ def make_power_fleet(n_hosts: int, mix: str = "mixed") -> List[object]:
     return [cycle[i % len(cycle)] for i in range(n_hosts)]
 
 
+def host_capacities(n_hosts: int, host_mips, host_pes=1
+                    ) -> Tuple[np.ndarray, np.ndarray]:
+    """``(pes [H] int, per-PE MIPS [H] float64)`` of the elastic fleet.
+
+    Each of ``host_mips`` and ``host_pes`` is one value for every host or
+    a sequence of host types cycled over the hosts by index, as CloudSim's
+    power examples assign ``hostType = i % HOST_TYPES`` (the same cycle
+    :func:`make_power_fleet` gives the power models)."""
+    mips = np.asarray(host_mips, np.float64).ravel()
+    pes = np.asarray(host_pes).ravel()
+    if mips.size == 0 or pes.size == 0:
+        raise ValueError("host_mips and host_pes need at least one value")
+    if pes.dtype.kind not in "iu" or np.any(pes < 1):
+        raise ValueError(f"host_pes must be whole numbers ≥ 1, not {host_pes}")
+    return (np.resize(pes.astype(np.int64), n_hosts),
+            np.resize(mips, n_hosts))
+
+
 def elastic_demand_trace(rng: random.Random, n_samples: int) -> List[float]:
     """Aggregate per-VM utilization trace in [0, 1]: triangle-wave diurnal
     swing + bounded random walk.
@@ -775,16 +793,19 @@ def check_demand(demand) -> np.ndarray:
 
 
 def make_elastic_scenario(n_hosts: int, n_vms: int, *, seed: int,
-                          n_samples: int, host_mips: float, vm_mips: float,
-                          model_mix: str = "mixed", demand=None
+                          n_samples: int, host_mips, vm_mips: float,
+                          model_mix: str = "mixed", demand=None,
+                          host_pes=1
                           ) -> Tuple[List[PowerHost], List[Vm], List[float]]:
-    """Hosts (uniform capacity, mixed power models), identical VMs, and the
-    cell's demand trace — shared verbatim by the OO and vec backends.  An
-    injected ``demand`` curve (trace replay) supersedes the seeded one."""
+    """Hosts (capacities and power models cycled by host type, see
+    :func:`host_capacities`), identical VMs, and the cell's demand trace —
+    shared verbatim by the OO and vec backends.  An injected ``demand``
+    curve (trace replay) supersedes the seeded one."""
     models = make_power_fleet(n_hosts, model_mix)
-    hosts = [PowerHost(num_pes=1, mips=host_mips, ram=1e12, bw=1e15,
-                       guest_scheduler="time", power_model=m)
-             for m in models]
+    pes, mips = host_capacities(n_hosts, host_mips, host_pes)
+    hosts = [PowerHost(num_pes=int(n), mips=float(m), ram=1e12, bw=1e15,
+                       guest_scheduler="time", power_model=model)
+             for n, m, model in zip(pes, mips, models)]
     vms = [Vm(CloudletSchedulerTimeShared(), num_pes=1, mips=vm_mips,
               ram=1.0, bw=1.0) for _ in range(n_vms)]
     trace = ([float(x) for x in demand] if demand is not None
@@ -929,15 +950,15 @@ class _HostFaultEntity(SimEntity):
 
 def _run_elastic_cell(backend, *, seed: int, n_hosts: int,
                       n_vms: int, n_samples: int, interval: float,
-                      host_mips: float, vm_mips: float, up_thr: float,
+                      host_mips, host_pes, vm_mips: float, up_thr: float,
                       lo_thr: float, cooldown: int, min_active: int,
                       init_active, model_mix: str, n_points: int,
                       fail_tbl: Optional[np.ndarray] = None,
                       demand=None) -> Dict:
     hosts, vms, trace = make_elastic_scenario(
         n_hosts, n_vms, seed=seed, n_samples=n_samples,
-        host_mips=host_mips, vm_mips=vm_mips, model_mix=model_mix,
-        demand=demand)
+        host_mips=host_mips, host_pes=host_pes, vm_mips=vm_mips,
+        model_mix=model_mix, demand=demand)
     mgr = ElasticDatacenterManager(
         hosts, vms, trace, vm_mips=vm_mips, up_thr=up_thr, lo_thr=lo_thr,
         cooldown_k=cooldown, min_active=min_active, init_active=init_active,
@@ -952,7 +973,7 @@ def _run_elastic_cell(backend, *, seed: int, n_hosts: int,
 
 def _power_batch_oo(backend, *, seeds=(0,), n_hosts: int = 8,
                     n_vms: int = 32, n_samples: int = 288,
-                    interval: float = 300.0, host_mips: float = 8000.0,
+                    interval: float = 300.0, host_mips=8000.0, host_pes=1,
                     vm_mips=1000.0, up_thr=0.8, lo_thr=0.3, cooldown=3,
                     min_active: int = 1, init_active=None,
                     model_mix: str = "mixed", n_points: int = 11,
@@ -979,7 +1000,7 @@ def _power_batch_oo(backend, *, seeds=(0,), n_hosts: int = 8,
         return _run_elastic_cell(
             backend, seed=int(seeds[i]), n_hosts=n_hosts, n_vms=n_vms,
             n_samples=n_samples, interval=interval, host_mips=host_mips,
-            vm_mips=float(axes["vm_mips"][i]),
+            host_pes=host_pes, vm_mips=float(axes["vm_mips"][i]),
             up_thr=float(axes["up_thr"][i]), lo_thr=float(axes["lo_thr"][i]),
             cooldown=int(axes["cooldown"][i]), min_active=min_active,
             init_active=init_active, model_mix=model_mix, n_points=n_points,

@@ -41,7 +41,9 @@ from .backend import scenario
 from .faults import FaultPlan
 from .power import (_broadcast_cells, _empty_outputs, _finalize,
                     _finalize_accumulators, _power_batch_oo,
-                    make_power_fleet, power_fault_table, power_points)
+                    host_capacities, make_power_fleet, power_fault_table,
+                    power_points)
+from .spans import span
 from .vec_engine import (BatchPlan, Done, Loop, StepSpec, VecEngine,
                          body_from_step, make_batch_entry)
 
@@ -104,6 +106,26 @@ def _even_counts(active, n_vms: int):
     return jnp.where(active, base + (rank < rem).astype(jnp.int32), 0)
 
 
+def table_segment(x, cast, n_points: int):
+    """``(segment, frac)`` of ``x = util·(n_points-1)`` in ``[0, n_points-1]``:
+    :func:`repro.core.power.table_segment`, vectorized.
+
+    ``cast`` is ``x`` cast to int32, which may be one off ``⌊x⌋``: on the
+    TPU, where f64 is a pair of f32, the cast reads the high word, so an
+    ``x`` just below a knot casts to the knot.  One comparison with ``x``
+    each way takes such a cast back, so ``seg = ⌊x⌋`` exactly, and
+    ``frac = x - seg`` is exact for ``seg ≤ x < seg + 1`` and equal to the
+    ``fmod(x, 1)`` of the OO side; both come from the one floor and cannot
+    disagree.  The top endpoint folds into the last segment with
+    ``frac = 1``.
+    """
+    seg = cast - (cast.astype(x.dtype) > x).astype(jnp.int32)
+    seg = seg + ((seg + 1).astype(x.dtype) <= x).astype(jnp.int32)
+    seg = jnp.minimum(seg, n_points - 2)
+    frac = jnp.where(x >= n_points - 1, 1.0, x - seg.astype(x.dtype))
+    return seg, frac
+
+
 def _power_build(params: _Params, s: _Statics, ops) -> Loop:
     """One elastic-datacenter cell: one loop iteration per trace interval
     (the driver's counter ``it`` is the interval index ``k``).
@@ -151,11 +173,11 @@ def _power_build(params: _Params, s: _Statics, ops) -> Loop:
         d = sl["trace"] * params.vm_mips                # per-VM MIPS demand
         demand = cnt.astype(params.cap.dtype) * d       # [H]
         util = jnp.minimum(demand / params.cap, 1.0)
-        # Exact energy accounting: which table segment, how far into it
-        # (repro.core.power.table_segment, vectorized; fmod is exact).
-        x = util * (s.n_points - 1)
-        seg = jnp.minimum(x.astype(jnp.int32), s.n_points - 2)
-        frac = jnp.where(x >= s.n_points - 1, 1.0, jnp.fmod(x, 1.0))
+        # Exact energy accounting: which table segment, how far into it.
+        # The min is the identity (util ≤ 1); it keeps the multiply from
+        # feeding the subtraction in table_segment (module docstring).
+        x = jnp.minimum(util * (s.n_points - 1), s.n_points - 1)
+        seg, frac = table_segment(x, x.astype(jnp.int32), s.n_points)
         hot = (seg[:, None] == seg_iota) & act[:, None]        # [H, P-1]
         seg_count = c.seg_count + hot.astype(jnp.int32)
         seg_frac = c.seg_frac + jnp.where(hot, frac[:, None], 0.0)
@@ -237,7 +259,7 @@ POWER_ENGINE = VecEngine("power_batch", _power_build, step_fusable=True)
 def _prepare_power(*, use_pallas: bool, seeds: Sequence[int] | np.ndarray = (0,),
                    n_hosts: int = 8, n_vms: int = 32,
                    n_samples: int = 288, interval: float = 300.0,
-                   host_mips: float = 8000.0, vm_mips=1000.0,
+                   host_mips=8000.0, host_pes=1, vm_mips=1000.0,
                    up_thr=0.8, lo_thr=0.3, cooldown=3,
                    min_active: int = 1, init_active: Optional[int] = None,
                    model_mix: str = "mixed", n_points: int = 11,
@@ -258,40 +280,44 @@ def _prepare_power(*, use_pallas: bool, seeds: Sequence[int] | np.ndarray = (0,)
         raise ValueError("interval must be > 0")
     seeds, axes, b = _broadcast_cells(seeds, dict(
         up_thr=up_thr, lo_thr=lo_thr, cooldown=cooldown, vm_mips=vm_mips))
-    if b and float(np.max(axes["vm_mips"])) > float(host_mips):
+    pes, mips = host_capacities(n_hosts, host_mips, host_pes)
+    if b and float(np.max(axes["vm_mips"])) > float(np.min(mips)):
         # Same constraint the OO reference enforces through time-shared
         # Host.suitable_for — reject up front so a vm_mips sweep axis that
         # crosses host_mips can't produce vec results with no OO semantics.
         raise ValueError(
-            f"vm_mips (max {np.max(axes['vm_mips'])}) must be ≤ host_mips "
-            f"({host_mips}): a VM must fit a time-shared host")
+            f"vm_mips (max {np.max(axes['vm_mips'])}) must be ≤ every "
+            f"host's per-PE host_mips ({np.min(mips)}): a VM must fit a "
+            f"time-shared host")
     fail_tbl = power_fault_table(fault_plan, n_hosts, n_samples, interval)
     if b == 0:
         return Done(_empty_outputs(n_hosts))
 
     from .power import elastic_demand_trace
     import random as _random
-    if demand is not None:
-        traces = np.broadcast_to(demand, (b, n_samples)).copy()
-    else:
-        traces = np.asarray([elastic_demand_trace(_random.Random(int(s)),
-                                                  n_samples)
-                             for s in seeds], np.float64)
-    models = make_power_fleet(n_hosts, model_mix)
-    cap = np.full(n_hosts, float(host_mips), np.float64)
-    table = np.asarray([power_points(m, n_points) for m in models],
-                       np.float64)
-    eff = table[:, -1] / cap
+    with span("sweep.prepare.build"):
+        if demand is not None:
+            traces = np.broadcast_to(demand, (b, n_samples)).copy()
+        else:
+            traces = np.asarray([elastic_demand_trace(
+                _random.Random(int(s)), n_samples) for s in seeds],
+                np.float64)
+        models = make_power_fleet(n_hosts, model_mix)
+        cap = pes.astype(np.float64) * mips
+        table = np.asarray([power_points(m, n_points) for m in models],
+                           np.float64)
+        eff = table[:, -1] / cap
     bc = lambda a: np.broadcast_to(a, (b,) + np.shape(a)).copy()
-    params = _Params(
-        trace=traces,
-        cap=bc(cap), eff=bc(eff),
-        up_thr=axes["up_thr"].astype(np.float64),
-        lo_thr=axes["lo_thr"].astype(np.float64),
-        vm_mips=axes["vm_mips"].astype(np.float64),
-        cooldown_k=axes["cooldown"].astype(np.int32),
-        init_active=np.full(b, init_active, np.int32),
-        fail_tbl=None if fail_tbl is None else bc(fail_tbl))
+    with span("sweep.prepare.pack"):
+        params = _Params(
+            trace=traces,
+            cap=bc(cap), eff=bc(eff),
+            up_thr=axes["up_thr"].astype(np.float64),
+            lo_thr=axes["lo_thr"].astype(np.float64),
+            vm_mips=axes["vm_mips"].astype(np.float64),
+            cooldown_k=axes["cooldown"].astype(np.int32),
+            init_active=np.full(b, init_active, np.int32),
+            fail_tbl=None if fail_tbl is None else bc(fail_tbl))
     statics = _Statics(int(n_hosts), int(n_points), int(n_samples),
                        int(n_vms), min_active, bool(use_pallas),
                        faults=fail_tbl is not None)
@@ -308,9 +334,12 @@ simulate_power_batch = make_batch_entry(
 
     ``seeds`` and the optional sweep axes (``up_thr``, ``lo_thr``,
     ``cooldown``, ``vm_mips`` — scalars or arrays broadcast against
-    ``seeds``) define the batch; each cell's demand trace is synthesized
-    from its seed (:func:`repro.core.power.elastic_demand_trace`) and
-    shared verbatim with the OO reference.  Returns a dict of per-cell
+    ``seeds``) define the batch.  ``host_mips`` (per PE) and ``host_pes``
+    are one value or a cycle of host types, as ``model_mix`` is
+    (:func:`repro.core.power.host_capacities`).  Each cell's demand trace
+    is synthesized from its seed
+    (:func:`repro.core.power.elastic_demand_trace`) and shared verbatim
+    with the OO reference.  Returns a dict of per-cell
     stats — per-host ``energy_wh [B, H]`` / ``sla_s`` / ``unserved_mips_s``
     plus their datacenter totals, integer ``migrations`` /
     ``scale_out_events`` / ``scale_in_events`` / ``final_active`` — and

@@ -41,6 +41,35 @@ def test_three_backends_bit_exact():
     assert (oo["energy_total_wh"] > 0).all()
 
 
+# CloudSim's power-example hosts: ML110 G4 and G5 by index, 2 PEs each.
+ML110 = dict(host_mips=[1860.0, 2660.0], host_pes=2, vm_mips=1500.0,
+             model_mix="spec")
+
+
+def test_host_types_bit_exact_across_backends():
+    cfg = dict(CFG, n_vms=13, **ML110)
+    oo = run_scenario("power_batch", backend="oo", **cfg)
+    vec = run_scenario("power_batch", backend="vec", **cfg)
+    _assert_all_equal(oo, vec, "oo vs vec, host types")
+    one_type = run_scenario("power_batch", backend="vec",
+                            **dict(cfg, host_mips=2660.0))
+    # the G4 hosts' smaller capacity raises their utilization, and so the
+    # energy the autoscaler's decisions leave behind
+    assert not np.array_equal(vec["energy_wh"], one_type["energy_wh"])
+
+
+def test_host_capacities_cycle_by_index():
+    from repro.core.power import host_capacities
+    pes, mips = host_capacities(5, [1860.0, 2660.0], 2)
+    assert pes.tolist() == [2] * 5
+    assert mips.tolist() == [1860.0, 2660.0, 1860.0, 2660.0, 1860.0]
+    pes, mips = host_capacities(3, 8000.0, [1, 4])
+    assert pes.tolist() == [1, 4, 1] and mips.tolist() == [8000.0] * 3
+    for bad in (0, 1.5, []):
+        with pytest.raises(ValueError, match="host_pes|at least one"):
+            host_capacities(3, 8000.0, bad)
+
+
 def test_run_sweep_report_populated_both_backends():
     for backend in ("vec", "oo"):
         out, rep = run_sweep("power_batch", backend=backend, **CFG)
@@ -151,6 +180,11 @@ def test_validation_errors():
             run_scenario("power_batch", backend=backend, seeds=[0],
                          n_hosts=4, n_vms=8, n_samples=4,
                          host_mips=8000.0, vm_mips=[4000.0, 9000.0])
+        # per PE, on every host type: 2000 fits a G5 PE, not a G4 one
+        with pytest.raises(ValueError, match="vm_mips"):
+            run_scenario("power_batch", backend=backend, seeds=[0],
+                         n_hosts=4, n_vms=8, n_samples=4,
+                         **dict(ML110, vm_mips=2000.0))
 
 
 def test_unknown_backend_errors_cleanly():

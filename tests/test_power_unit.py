@@ -9,6 +9,7 @@ selection policies' first-occurrence tie-breaking (which the vec engine's
 """
 import math
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -124,6 +125,59 @@ def test_table_segment_frac_equals_direct_difference():
         x = float(u) * 10
         s, frac = table_segment(float(u), 11)
         assert frac == x - math.floor(x)
+
+
+# -- the vec loop's decomposition under a cast that may round ----------------
+
+P = 11
+WITNESS = 0.9999999932878628      # a 2-VM host just below the 10 % knot
+CASTS = {
+    "exact": lambda x: x.astype(np.int32),
+    # the TPU's f64 is an f32 pair; its cast reads the high word, which
+    # rounds to nearest: 1 - 6.7e-9 casts to 1
+    "chip": lambda x: x.astype(np.float32).astype(np.int32),
+    # one below the floor, for the comparison that takes a cast back up
+    "low": lambda x: np.maximum(x.astype(np.int32) - 1, 0),
+}
+
+
+def _knots_and_neighbours():
+    # Above 0, the least normal double: XLA flushes subnormals to zero.
+    xs = [WITNESS, 0.0, np.finfo(np.float64).tiny]
+    for k in range(1, P):
+        xs += [float(k), np.nextafter(float(k), 0.0),
+               np.nextafter(float(k), 11.0)]
+    xs += list(np.random.default_rng(7).uniform(0, P - 1, 200))
+    return np.clip(np.asarray(xs, np.float64), 0.0, P - 1)
+
+
+@pytest.mark.parametrize("cast", sorted(CASTS))
+def test_vec_table_segment_equals_floor_and_fmod(cast):
+    """The loop's ``(seg, frac)`` is the reference's ``min(int(x), P-2)``
+    and ``fmod(x, 1)`` bit for bit, whichever way the int cast rounded."""
+    from repro.core import vec_engine
+    from repro.core.vec_power import table_segment as vec_table_segment
+    x = _knots_and_neighbours()
+    want_seg = np.minimum(x.astype(np.int64), P - 2)
+    want_frac = np.where(x >= P - 1, 1.0, np.fmod(x, 1.0))
+    with vec_engine.x64():
+        seg, frac = vec_table_segment(jnp.asarray(x),
+                                      jnp.asarray(CASTS[cast](x)), P)
+    seg, frac = np.asarray(seg), np.asarray(frac)
+    assert frac.dtype == np.float64
+    assert np.array_equal(seg, want_seg)
+    assert frac.tobytes() == want_frac.tobytes()
+    at = {float(v): (int(a), float(b)) for v, a, b in zip(x, seg, frac)}
+    assert at[WITNESS] == (0, WITNESS)
+    assert at[0.0] == (0, 0.0)
+    assert at[P - 1.0] == (P - 2, 1.0)
+
+
+def test_chip_cast_rounds_the_witness_up():
+    """What the chip-like cast above stands for: the witness casts to the
+    knot above it, so a segment taken from the cast alone is one high."""
+    assert CASTS["chip"](np.float64(WITNESS)) == 1
+    assert CASTS["exact"](np.float64(WITNESS)) == 0
 
 
 # -- fleet factory -------------------------------------------------------------

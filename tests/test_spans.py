@@ -14,9 +14,12 @@ from repro.core import backend, spans, vec_engine
 from repro.core.backend import run_sweep
 from repro.core.sweep import SweepConfig
 from repro.core.vec_llmserve import LLMSERVE_ENGINE, _prepare_llmserve
+from repro.core.vec_power import POWER_ENGINE, _prepare_power
 
 KIND = "llmserve_batch"
 PARAMS = dict(seeds=np.arange(8), n_requests=40)
+POWER = dict(seeds=np.arange(8), n_hosts=6, n_vms=20, n_samples=24,
+             up_thr=np.linspace(0.5, 0.9, 8))
 CONFIGS = {"compact": SweepConfig(compact=True, chunk_size=4,
                                   segment_iters=16),
            "chunked": SweepConfig(chunk_size=4)}
@@ -25,10 +28,10 @@ NAMES = {"sweep", "sweep.validate", "sweep.prepare", "sweep.prepare.build",
          "sweep.finalize"}
 
 
-def _traced(directory, config):
-    run_sweep(KIND, PARAMS, config=config)          # compiles untraced
+def _traced(directory, config, kind=KIND, params=PARAMS):
+    run_sweep(kind, params, config=config)          # compiles untraced
     with jax.profiler.trace(str(directory)):
-        res = run_sweep(KIND, PARAMS, config=config)
+        res = run_sweep(kind, params, config=config)
     path = max(glob.glob(os.path.join(str(directory), "**", "*.xplane.pb"),
                          recursive=True))
     return res, programspans.load_program(path)
@@ -41,8 +44,14 @@ def traced(request, tmp_path_factory):
     return request.param, res, found
 
 
-def test_every_span_nests_under_one_sweep(traced):
-    path, _, found = traced
+@pytest.fixture(scope="module", params=sorted(CONFIGS))
+def traced_power(request, tmp_path_factory):
+    res, found = _traced(tmp_path_factory.mktemp("power-" + request.param),
+                         CONFIGS[request.param], "power_batch", POWER)
+    return request.param, res, found
+
+
+def _assert_nested(path, found):
     sweeps = [p for p in found if p[0] == "sweep"]
     assert len(sweeps) == 1
     _, lo, hi, _ = sweeps[0]
@@ -53,6 +62,16 @@ def test_every_span_nests_under_one_sweep(traced):
     for child in ("sweep.prepare.build", "sweep.prepare.pack"):
         (_, s, e, _), = [p for p in found if p[0] == child]
         assert plo <= s and e <= phi
+
+
+def test_every_span_nests_under_one_sweep(traced):
+    path, _, found = traced
+    _assert_nested(path, found)
+
+
+def test_power_sweep_spans_nest_under_one_sweep(traced_power):
+    path, _, found = traced_power
+    _assert_nested(path, found)
 
 
 def test_sweep_span_carries_the_report(traced):
@@ -75,17 +94,14 @@ def test_sweep_span_carries_the_report(traced):
     assert len(found) <= 3 * rep.dispatches + 8
 
 
-def test_h2d_bytes_from_shapes(traced):
-    path, res, found = traced
-    plan = _prepare_llmserve(use_pallas=False, **PARAMS)
-    b, j, k = plan.params.packed.shape
-    itemsize = plan.params.packed.dtype.itemsize
-    rep = res.report
-    lanes = 4
-    lane_params = lanes * j * k * itemsize
+def _h2d_from_shapes(engine, plan, path, rep, lanes=4):
+    """The bytes a sweep of ``plan`` on ``lanes`` lanes hands the device, as
+    the shapes of its lane params and loop state give them."""
+    lane_params = sum(lanes * leaf[0].nbytes
+                      for leaf in jax.tree_util.tree_leaves(plan.params))
     if path == "compact":
         with vec_engine.x64():
-            proto = vec_engine.state_prototype(LLMSERVE_ENGINE, plan.statics,
+            proto = vec_engine.state_prototype(engine, plan.statics,
                                                plan.params)
         state = sum(lanes * int(np.prod(sd.shape)) * sd.dtype.itemsize
                     for sd in jax.tree_util.tree_leaves(proto))
@@ -100,9 +116,36 @@ def test_h2d_bytes_from_shapes(traced):
     else:
         assert rep.param_uploads == rep.n_chunks
         want = rep.n_chunks * lane_params
+    return want
+
+
+def test_h2d_bytes_from_shapes(traced):
+    path, res, found = traced
+    plan = _prepare_llmserve(use_pallas=False, **PARAMS)
+    rep = res.report
+    want = _h2d_from_shapes(LLMSERVE_ENGINE, plan, path, rep)
     assert rep.h2d_bytes == want
     (_, _, _, stats), = [p for p in found if p[0] == "sweep"]
     assert stats["h2d_bytes"] == want
+
+
+def test_power_sweep_counters_from_shapes(traced_power):
+    """Power's lane params are a pytree of per-cell arrays (trace, host
+    capacity and efficiency, thresholds): every leaf is counted."""
+    path, res, found = traced_power
+    plan = _prepare_power(use_pallas=False, **POWER)
+    rep = res.report
+    want = _h2d_from_shapes(POWER_ENGINE, plan, path, rep)
+    assert rep.h2d_bytes == want
+    (_, _, _, stats), = [p for p in found if p[0] == "sweep"]
+    assert stats["h2d_bytes"] == want
+    assert stats["param_uploads"] == rep.param_uploads
+    # 8 cells, 24 intervals: 2 chunks of 4 lanes, or 4 lanes of 16-interval
+    # segments, each cell taking 2.
+    assert stats["dispatches"] == rep.dispatches == (
+        rep.segments if path == "compact" else rep.n_chunks)
+    assert rep.dispatches == (4 if path == "compact" else 2)
+    assert sum(p[0] == "sweep.dispatch" for p in found) == rep.dispatches
 
 
 @pytest.mark.parametrize("path", sorted(CONFIGS))
