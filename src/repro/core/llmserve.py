@@ -29,9 +29,11 @@ regional outage via ``offline_region``) is dropped.  TTFT (time to first
 token) is the last stage's prompt completion plus a first-token egress.
 
 This module owns everything both backends share — the libm-free workload
-feeders (golden-fixture bit-stability), per-cell routing tables (service /
+feeders (golden-fixture bit-stability), the routing tables (service /
 hop / egress / bias matrices, all precomputed host-side so neither backend
-multiplies inside its decision loop — no FMA-contraction hazard), the
+multiplies inside its decision loop — no FMA-contraction hazard; built
+once per distinct request stream and gathered per cell, see
+:class:`LLMServeBatch`), the
 routing rule itself, and the host-side summary — plus the OO reference:
 a broker entity driving REQUEST_SUBMIT/REQUEST_RETURN events through a
 ``Simulation``.  The vec implementation (:mod:`repro.core.vec_llmserve`)
@@ -46,7 +48,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Dict, NamedTuple, Optional, Sequence
+from typing import Any, Dict, NamedTuple, Optional
 
 import numpy as np
 
@@ -163,8 +165,9 @@ class LLMFaults(NamedTuple):
 
 @dataclass(frozen=True)
 class LLMServeCell:
-    """One cell's precomputed routing tables — shared verbatim by the OO
-    broker and the vec engine, so decision bit-identity reduces to both
+    """One cell's routing tables, gathered from its batch's factored build
+    (:class:`LLMServeBatch`): the OO broker reads them, and the vec engine
+    reads the same doubles packed, so decision bit-identity reduces to both
     backends evaluating the same adds/max/compares over the same doubles.
     Under a :class:`~repro.core.faults.FaultPlan` the per-request rows are
     in effective-submit order and ``eligible`` folds in machine/region
@@ -189,113 +192,176 @@ class LLMServeCell:
     fx: Optional[LLMFaults] = None
 
 
-def build_cell(seed: int, placement: np.ndarray,
-               machines: Dict[str, np.ndarray], regions: np.ndarray,
-               topo: InterDCTopology, *, n_requests: int, n_regions: int,
-               n_layers: int, mean_gap_s: float, locality_weight: float,
-               offline_region: int, offline_frac: float, slo_ttft_s: float,
-               kv_penalty_s: float, prompt_tokens, decode_tokens,
-               fault_plan: Optional[FaultPlan] = None,
-               retry: Optional[RetryPolicy] = None,
-               timeout_s: float = math.inf,
-               workload: Optional[Dict[str, Any]] = None) -> LLMServeCell:
-    """Workload + routing tables for one (seed, placement, axes) cell.
-    An injected ``workload`` (a validated trace-replay stream) replaces
-    the seeded feeders — every cell then shares the recorded stream."""
-    wl = (dict(workload) if workload is not None else llmserve_workload(
-        int(seed), n_requests, n_regions,
-        mean_gap_s=float(mean_gap_s), offline_frac=offline_frac,
-        prompt_tokens=prompt_tokens, decode_tokens=decode_tokens))
-    faulted = fault_plan is not None or math.isfinite(timeout_s)
-    gave_up = attempts = perm = None
-    plan = fault_plan if fault_plan is not None else FaultPlan()
-    if faulted:
-        # Transient failures resolve at the *original* submit times, then
-        # a stable sort restores nondecreasing effective-submit order —
-        # the shared event order both backends process.
-        res = apply_transient(plan, retry, wl["submit"],
-                              seed=plan.seed * 1_000_003 + int(seed))
-        perm = np.argsort(res.eff_submit, kind="stable")
-        wl = {k: v[perm] for k, v in wl.items()}
-        wl["submit"] = res.eff_submit[perm]
-        gave_up, attempts = res.gave_up[perm], res.attempts[perm]
-    pl = np.asarray(placement, np.int64)               # [P, S]
-    n_pipes, n_stages = pl.shape
-    p_tok = wl["prompt_tok"].astype(np.float64)        # [J]
-    d_tok = wl["decode_tok"].astype(np.float64)
+def packed_layout(n_pipes: int, n_stages: int) -> Dict[str, slice]:
+    """Where each field sits in a cell's column index: ``submit |
+    svc[P·S] | hop[P·S] | tail[P] | first_extra[P] | bias[P] |
+    eligible[P] | kv_need`` (a row of the vec engine's packed table, up
+    to ``kv_need``), then ``wan[P] | base_eligible[P]``, which only the
+    host reads."""
+    ps = n_pipes * n_stages
+    out, lo = {}, 0
+    for name, width in (("submit", 1), ("svc", ps), ("hop", ps),
+                        ("tail", n_pipes), ("first_extra", n_pipes),
+                        ("bias", n_pipes), ("eligible", n_pipes),
+                        ("kv_need", 1), ("wan", n_pipes),
+                        ("base_eligible", n_pipes)):
+        out[name] = slice(lo, lo + width)
+        lo += width
+    return out
+
+
+@dataclass(frozen=True)
+class LLMServeBatch:
+    """A batch's routing tables, factored.
+
+    Every table entry depends on one request and on something small that
+    the cell's placement picks: a machine (service; last-stage prefill +
+    first token), a region (ingress; egress), a region pair (activation
+    hop), or a pipeline's region path, KV capacity, locality weight,
+    outage state and, under faults, machines (WAN total, bias,
+    eligibility).  So each distinct request stream gets one request
+    table ``[J, C]`` holding those columns, and a cell is its stream's
+    table gathered by a column index built from its placement and axes
+    alone.  Cells that share ``(seed, mean_gap_s)`` (a policy search's
+    members over common scenario seeds) share a stream; with every seed
+    distinct there is one stream a cell.
+
+    ``batch[i]`` materialises cell ``i``'s :class:`LLMServeCell` (the OO
+    broker and tests read it); :meth:`columns` gathers into a caller's
+    buffer (the vec engine's packed table); :func:`summarize` reads the
+    request tables in place."""
+    table: np.ndarray         # [U, J, C]  f64 request table per stream
+    stream: np.ndarray        # [B]        i64 the stream cell b draws
+    cols: np.ndarray          # [B, N]     i64 its columns (packed_layout)
+    placement: np.ndarray     # [B, P, S]  i64 machine id per stage
+    # Per stream [U, J]: submit, src, prompt_tok, decode_tok, online,
+    # kv_need; faulted also gave_up, attempts and perm (as LLMFaults).
+    requests: Dict[str, np.ndarray]
+    n_machines: int
+    slo_ttft_s: float
+    timeout_s: float
+    windows: Optional[tuple] = None   # LLMFaults.windows; None: unfaulted
+
+    def __len__(self) -> int:
+        return len(self.stream)
+
+    @property
+    def layout(self) -> Dict[str, slice]:
+        return packed_layout(*self.placement.shape[1:])
+
+    def columns(self, i: int, n: Optional[int] = None, out=None):
+        """Cell ``i``'s first ``n`` columns (all by default), ``[J, n]``."""
+        # mode="clip" lets numpy write `out` unbuffered; no index is out
+        # of range.
+        return np.take(self.table[self.stream[i]], self.cols[i, :n],
+                       axis=1, out=out, mode="clip")
+
+    def __getitem__(self, i: int) -> LLMServeCell:
+        row, f = self.columns(i), self.layout
+        req = {k: v[self.stream[i]] for k, v in self.requests.items()}
+        pl = self.placement[i]
+        shape = (row.shape[0],) + pl.shape
+        fx = None if self.windows is None else LLMFaults(
+            windows=self.windows,
+            base_eligible=row[:, f["base_eligible"]] != 0,
+            gave_up=req["gave_up"], attempts=req["attempts"],
+            perm=req["perm"], timeout_s=self.timeout_s)
+        return LLMServeCell(
+            submit=req["submit"], src=req["src"],
+            prompt_tok=req["prompt_tok"], decode_tok=req["decode_tok"],
+            online=req["online"], kv_need=req["kv_need"],
+            svc=row[:, f["svc"]].reshape(shape),
+            hop=row[:, f["hop"]].reshape(shape), tail=row[:, f["tail"]],
+            first_extra=row[:, f["first_extra"]], wan=row[:, f["wan"]],
+            bias=row[:, f["bias"]], eligible=row[:, f["eligible"]] != 0,
+            placement=pl, n_machines=self.n_machines,
+            slo_ttft_s=self.slo_ttft_s, fx=fx)
+
+
+def _unique_rows(key: np.ndarray):
+    """``(first, inverse)`` over the distinct rows of an int ``[N, k]``
+    key, taken in lexicographic order: each distinct row's first index,
+    and each index's distinct row (``np.unique(key, axis=0)`` gives the
+    same, about ten times slower)."""
+    order = np.lexsort(key.T[::-1])
+    ordered = key[order]
+    new = np.ones(len(key), bool)
+    np.any(ordered[1:] != ordered[:-1], axis=1, out=new[1:])
+    inverse = np.empty(len(key), np.int64)
+    inverse[order] = np.cumsum(new) - 1
+    return order[new], inverse
+
+
+def _draw_streams(seeds, gaps, draw, workload, plan: FaultPlan,
+                  retry: Optional[RetryPolicy], faulted: bool):
+    """Each distinct request stream, drawn once: ``(stream, requests)``,
+    the cells' stream ids ``[B]`` and the streams' per-request arrays
+    stacked ``[U, J]``.  A stream is keyed on what it depends on: the
+    seed and ``mean_gap_s``, or an injected ``workload`` alone, and the
+    seed again under faults (transient retries draw from it)."""
+    zeros = np.zeros_like(seeds)
+    key = np.stack([seeds if workload is None or faulted else zeros,
+                    gaps.view(np.int64) if workload is None else zeros],
+                   axis=1)
+    first, stream = _unique_rows(key)
+    streams = []
+    for i in first:
+        seed = int(seeds[i])
+        req = (dict(workload) if workload is not None
+               else draw(seed, float(gaps[i])))
+        if faulted:
+            # Transient failures resolve at the *original* submit times,
+            # then a stable sort restores nondecreasing effective-submit
+            # order — the shared event order both backends process.
+            res = apply_transient(plan, retry, req["submit"],
+                                  seed=plan.seed * 1_000_003 + seed)
+            perm = np.argsort(res.eff_submit, kind="stable")
+            req = {k: v[perm] for k, v in req.items()}
+            req.update(submit=res.eff_submit[perm],
+                       gave_up=res.gave_up[perm],
+                       attempts=res.attempts[perm], perm=perm)
+        streams.append(req)
+    requests = {k: np.stack([r[k] for r in streams]) for k in streams[0]}
+    requests["kv_need"] = requests["prompt_tok"] + requests["decode_tok"]
+    return stream, requests
+
+
+def _request_blocks(requests, machines, regions, topo: InterDCTopology,
+                    n_layers: int, n_stages: int, plan: FaultPlan):
+    """The request table's columns per machine, region and region pair,
+    ``{block: [U, J, width]}``.  Each entry is the IEEE expression, in
+    the order, that the cell's own table entry would evaluate."""
+    n_streams, n = requests["submit"].shape
+    r = np.arange(topo.n_dcs)
+    p_tok = requests["prompt_tok"].astype(np.float64)[..., None]  # [U,J,1]
+    d_tok = requests["decode_tok"].astype(np.float64)[..., None]
+    src = requests["src"][..., None]
     layers = float(n_layers) / float(n_stages)         # layers per stage
-    # Service occupancy per (request, pipeline, stage): prefill then decode
-    # at the stage machine's token-layers/s rates.
-    prompt_svc = (p_tok[:, None, None] * layers
-                  / machines["prompt_tls"][pl][None])  # [J, P, S]
-    decode_svc = (d_tok[:, None, None] * layers
-                  / machines["decode_tls"][pl][None])
-    svc = prompt_svc + decode_svc
-    # WAN legs: ingress into stage 0, activation hops between consecutive
-    # stage regions, response egress from the last stage.  Active ``link``
-    # fault windows (global for this scenario) stretch every WAN leg of
-    # the requests submitted inside them by the severity factor.
-    m_region = regions[pl]                             # [P, S]
-    ingress_rows = topo.delay_rows(wl["src"],
-                                   p_tok * IN_BYTES_PER_TOKEN)  # [J, R]
-    act_bytes = p_tok * ACT_BYTES_PER_TOKEN
-    hop = np.zeros((n_requests, n_pipes, n_stages), np.float64)
-    hop[:, :, 0] = ingress_rows[:, m_region[:, 0]]
-    for s in range(1, n_stages):
-        hop[:, :, s] = topo.delay_pairs(m_region[None, :, s - 1],
-                                        m_region[None, :, s],
-                                        act_bytes[:, None])
-    tail = topo.delay_pairs(m_region[None, :, -1], wl["src"][:, None],
-                            (d_tok * OUT_BYTES_PER_TOKEN)[:, None])  # [J, P]
-    first_delay = topo.delay_pairs(m_region[None, :, -1],
-                                   wl["src"][:, None], FIRST_TOKEN_BYTES)
+    # Service occupancy per (request, machine): prefill then decode at the
+    # machine's token-layers/s rates.
+    prompt_svc = p_tok * layers / machines["prompt_tls"]          # [U,J,M]
+    svc = prompt_svc + d_tok * layers / machines["decode_tls"]
+    # WAN legs: ingress into a stage-0 region, activation hops between
+    # region pairs, response egress and the first token from a last-stage
+    # region.  Active ``link`` fault windows (global for this scenario)
+    # stretch every WAN leg of the requests submitted inside them by the
+    # severity factor.
+    ingress = topo.delay_pairs(src, r, p_tok * IN_BYTES_PER_TOKEN)
+    act = topo.delay_pairs(r[:, None], r,
+                           (p_tok * ACT_BYTES_PER_TOKEN)[..., None])
+    tail = topo.delay_pairs(r, src, d_tok * OUT_BYTES_PER_TOKEN)
+    first_delay = topo.delay_pairs(r, src, FIRST_TOKEN_BYTES)
     if plan.has("link"):
-        wan_f = plan.degrade_factor(wl["submit"], 1)[:, 0]       # [J]
-        hop *= wan_f[:, None, None]
-        tail *= wan_f[:, None]
-        first_delay *= wan_f[:, None]
-    first_extra = prompt_svc[:, :, -1] + first_delay
-    wan = hop.sum(axis=2) + tail
-    # KV-cache occupancy: hard eligibility against the pipeline's smallest
-    # capacity, plus a precomputed pressure bias toward VRAM headroom.
-    kv_need = wl["prompt_tok"] + wl["decode_tok"]      # [J] i64
-    pipe_kv = machines["kv_tokens"][pl].min(axis=1)    # [P] i64
-    bias = ((float(locality_weight) - 1.0) * wan
-            + float(kv_penalty_s)
-            * (kv_need.astype(np.float64)[:, None]
-               / pipe_kv.astype(np.float64)[None, :]))
-    pipe_online = np.all(m_region != int(offline_region), axis=1)  # [P]
-    eligible = (kv_need[:, None] <= pipe_kv[None, :]) & pipe_online[None, :]
-    fx = None
-    if faulted:
-        base_eligible = eligible
-        # Machine crash windows + region outages (expanded to member
-        # machines) take whole pipelines down for the requests submitted
-        # inside them; both views — this baked table and the OO broker's
-        # live counters — evaluate the same half-open windows.
-        down = plan.down_mask("node", wl["submit"], len(regions))
-        if plan.has("region"):
-            down |= plan.down_mask(
-                "region", wl["submit"], n_regions)[:, regions]
-        pipe_up = ~np.any(down[:, pl], axis=2)                   # [J, P]
-        eligible = base_eligible & pipe_up & ~gave_up[:, None]
-        windows = []
-        tgt, ts, te, _ = plan.select("node")
-        windows += list(zip(tgt.tolist(), ts.tolist(), te.tolist()))
-        r_tgt, r_ts, r_te, _ = plan.select("region")
-        for r, a, z in zip(r_tgt.tolist(), r_ts.tolist(), r_te.tolist()):
-            windows += [(int(m), a, z)
-                        for m in np.flatnonzero(regions == r)]
-        fx = LLMFaults(windows=tuple(windows),
-                       base_eligible=base_eligible, gave_up=gave_up,
-                       attempts=attempts, perm=perm,
-                       timeout_s=float(timeout_s))
-    return LLMServeCell(
-        submit=wl["submit"], src=wl["src"], prompt_tok=wl["prompt_tok"],
-        decode_tok=wl["decode_tok"], online=wl["online"], kv_need=kv_need,
-        svc=svc, hop=hop, tail=tail, first_extra=first_extra, wan=wan,
-        bias=bias, eligible=eligible, placement=pl,
-        n_machines=len(regions), slo_ttft_s=float(slo_ttft_s), fx=fx)
+        wan_f = plan.degrade_factor(requests["submit"].ravel(), 1
+                                    ).reshape(n_streams, n, 1)
+        ingress *= wan_f
+        act *= wan_f[..., None]
+        tail *= wan_f
+        first_delay *= wan_f
+    return dict(submit=requests["submit"][..., None], svc=svc,
+                first_extra=prompt_svc + first_delay[..., regions],
+                ingress=ingress, act=act.reshape(n_streams, n, -1),
+                tail=tail, kv_need=requests["kv_need"][..., None])
 
 
 def route_request(free, cell: LLMServeCell, j: int, eligible=None,
@@ -341,12 +407,12 @@ def route_request(free, cell: LLMServeCell, j: int, eligible=None,
     return best, best_fin, best_ttft, best_dep
 
 
-def summarize(out: Dict[str, Any], cells: Sequence[LLMServeCell]
-              ) -> Dict[str, Any]:
+def summarize(out: Dict[str, Any], cells: LLMServeBatch) -> Dict[str, Any]:
     """Batch-level serving metrics from per-request ``dst``/``finish``/
     ``ttft`` and the per-slot KV counters — one shared numpy routine so
     every aggregate (guarded means, argmax tie-breaks, busy-time scatters)
-    is computed identically for both backends."""
+    is computed identically for both backends.  Each cell's service and
+    WAN times are read from its stream's request table in place."""
     out = dict(out)
     dst = out["dst"] = np.asarray(out["dst"], np.int64)          # [B, J]
     finish = out["finish"] = np.asarray(out["finish"], np.float64)
@@ -354,10 +420,8 @@ def summarize(out: Dict[str, Any], cells: Sequence[LLMServeCell]
     kv_used = out["kv_used"] = np.asarray(out["kv_used"], np.int64)
     b, n_requests = dst.shape
     n_pipes = kv_used.shape[1]
-    n_machines = cells[0].n_machines if cells else 0
-    submit = np.stack([c.submit for c in cells])
-    decode_tok = np.stack([c.decode_tok for c in cells])
-    slo = np.asarray([c.slo_ttft_s for c in cells], np.float64)[:, None]
+    submit = cells.requests["submit"][cells.stream]
+    decode_tok = cells.requests["decode_tok"][cells.stream]
     served_m = dst >= 0                                          # [B, J]
     served = out["served"] = served_m.sum(axis=-1)
     out["dropped"] = n_requests - served
@@ -368,21 +432,26 @@ def summarize(out: Dict[str, Any], cells: Sequence[LLMServeCell]
     out["latency_mean_s"] = np.where(served > 0, lat_total / denom, 0.0)
     ttft_total = np.sum(np.where(served_m, ttft, 0.0), axis=-1)
     out["ttft_mean_s"] = np.where(served > 0, ttft_total / denom, 0.0)
-    out["slo_violations"] = np.sum(served_m & (ttft > slo), axis=-1)
+    out["slo_violations"] = np.sum(served_m & (ttft > cells.slo_ttft_s),
+                                   axis=-1)
     out["tokens_out"] = np.sum(np.where(served_m, decode_tok, 0), axis=-1)
     p_iota = np.arange(n_pipes)
     out["pipe_requests"] = np.sum(dst[:, :, None] == p_iota, axis=1)
-    busy = np.zeros((b, n_machines), np.float64)
-    kv_m = np.zeros((b, n_machines), np.int64)
+    busy = np.zeros((b, cells.n_machines), np.float64)
+    kv_m = np.zeros((b, cells.n_machines), np.int64)
     wan_total = np.zeros(b, np.float64)
     picked = np.clip(dst, 0, None)
-    for i, c in enumerate(cells):
+    f, pl = cells.layout, cells.placement
+    svc_cols = cells.cols[:, f["svc"]].reshape(pl.shape)        # [B, P, S]
+    wan_cols = cells.cols[:, f["wan"]]                          # [B, P]
+    for i in range(b):
         rows = np.flatnonzero(served_m[i])
-        stage_svc = c.svc[rows, picked[i, rows]]          # [n, S]
-        stage_mach = c.placement[picked[i, rows]]         # [n, S]
-        np.add.at(busy[i], stage_mach.ravel(), stage_svc.ravel())
-        np.add.at(kv_m[i], c.placement.ravel(), kv_used[i].ravel())
-        wan_total[i] = c.wan[rows, picked[i, rows]].sum()
+        pick = picked[i, rows]
+        table = cells.table[cells.stream[i]]
+        stage_svc = table[rows[:, None], svc_cols[i, pick]]   # [n, S]
+        np.add.at(busy[i], pl[i, pick].ravel(), stage_svc.ravel())
+        np.add.at(kv_m[i], pl[i].ravel(), kv_used[i].ravel())
+        wan_total[i] = table[rows, wan_cols[i, pick]].sum()
     out["machine_busy_s"] = busy
     out["kv_assigned_tokens"] = kv_m
     out["wan_delay_total_s"] = wan_total
@@ -390,17 +459,35 @@ def summarize(out: Dict[str, Any], cells: Sequence[LLMServeCell]
     out["utilization"] = np.where(out["makespan"][:, None] > 0,
                                   busy / span, 0.0)
     out["busiest_machine"] = np.argmax(busy, axis=-1)
-    if cells and cells[0].fx is not None:
+    if cells.windows is not None:
         # Faulted runs: per-request arrays go back to original submission
-        # order (the cells were stable-sorted by effective submit), and
+        # order (the streams were stable-sorted by effective submit), and
         # the summary gains the effective submits + retry counts.
-        inv = np.stack([np.argsort(c.fx.perm) for c in cells])
+        inv = np.argsort(cells.requests["perm"], axis=-1)[cells.stream]
         for k in ("dst", "finish", "ttft"):
             out[k] = np.take_along_axis(out[k], inv, axis=-1)
         out["submit"] = np.take_along_axis(submit, inv, axis=-1)
-        out["retries"] = np.stack(
-            [np.sum(c.fx.attempts - 1) for c in cells])
+        out["retries"] = np.sum(cells.requests["attempts"] - 1,
+                                axis=-1)[cells.stream]
     return out
+
+
+def _fault_view(plan: FaultPlan, submit: np.ndarray, regions: np.ndarray,
+                n_regions: int):
+    """``(down, windows)``: which machines are down at each request's
+    submit (``[U, J, M]`` bool), and the plan's machine crash windows
+    with region outages expanded to member machines (the OO broker's
+    live view).  Both evaluate the same half-open windows."""
+    down = plan.down_mask("node", submit.ravel(), len(regions))
+    if plan.has("region"):
+        down |= plan.down_mask("region", submit.ravel(),
+                               n_regions)[:, regions]
+    tgt, ts, te, _ = plan.select("node")
+    windows = list(zip(tgt.tolist(), ts.tolist(), te.tolist()))
+    r_tgt, r_ts, r_te, _ = plan.select("region")
+    for r, a, z in zip(r_tgt.tolist(), r_ts.tolist(), r_te.tolist()):
+        windows += [(int(m), a, z) for m in np.flatnonzero(regions == r)]
+    return down.reshape(submit.shape + (-1,)), tuple(windows)
 
 
 def build_cells(*, seeds, n_machines: int = 6, n_regions: int = 3,
@@ -415,8 +502,9 @@ def build_cells(*, seeds, n_machines: int = 6, n_regions: int = 3,
                 fault_plan: Optional[FaultPlan] = None,
                 retry: Optional[RetryPolicy] = None,
                 timeout_s: float = math.inf, workload=None):
-    """Validated per-cell table construction — the shared front half of
-    both backends' batch handlers.
+    """Validated routing tables of a batch, ``(LLMServeBatch, B)`` —
+    the shared front half of both backends' batch handlers, and ``(None,
+    0)`` for an empty batch.
 
     ``seeds`` and the sweep axes ``mean_gap_s`` / ``locality_weight`` /
     ``offline_region`` broadcast to the batch; ``placement`` is one
@@ -488,21 +576,94 @@ def build_cells(*, seeds, n_machines: int = 6, n_regions: int = 3,
     offs = axes["offline_region"].astype(np.int64)
     if b and np.max(offs) >= n_regions:
         raise ValueError(f"offline_region must be < n_regions={n_regions}")
+    if b == 0:
+        return None, 0
+    n_pipes, n_stages = pl.shape[1:]
     topo = InterDCTopology(int(n_regions), link_bw=link_bw,
                            hop_latency_s=hop_latency_s)
-    cells = [build_cell(
-        int(seeds[i]), pl[i], machines, regions, topo,
-        n_requests=int(n_requests), n_regions=int(n_regions),
-        n_layers=int(n_layers),
-        mean_gap_s=float(axes["mean_gap_s"][i]),
-        locality_weight=float(axes["locality_weight"][i]),
-        offline_region=int(offs[i]), offline_frac=float(offline_frac),
-        slo_ttft_s=float(slo_ttft_s), kv_penalty_s=float(kv_penalty_s),
-        prompt_tokens=prompt_tokens, decode_tokens=decode_tokens,
-        fault_plan=fault_plan, retry=retry, timeout_s=float(timeout_s),
-        workload=workload)
-        for i in range(b)]
-    return cells, b
+    faulted = fault_plan is not None or math.isfinite(timeout_s)
+    plan = fault_plan if fault_plan is not None else FaultPlan()
+    stream, requests = _draw_streams(
+        seeds, np.asarray(axes["mean_gap_s"], np.float64),
+        lambda seed, gap: llmserve_workload(
+            seed, int(n_requests), int(n_regions), mean_gap_s=gap,
+            offline_frac=float(offline_frac), prompt_tokens=prompt_tokens,
+            decode_tokens=decode_tokens),
+        workload, plan, retry, faulted)
+    blocks = _request_blocks(requests, machines, regions, topo,
+                             int(n_layers), n_stages, plan)
+    at, c0 = {}, 0                       # each block's first column
+    for name, block in blocks.items():
+        at[name], c0 = c0, c0 + block.shape[-1]
+    # Per (cell, pipeline): the columns its stage tables take.
+    m_region = regions[pl]                                  # [B, P, S]
+    hop_cols = np.concatenate(
+        [at["ingress"] + m_region[..., :1],
+         at["act"] + m_region[..., :-1] * int(n_regions) + m_region[..., 1:]],
+        axis=-1)
+    tail_cols = at["tail"] + m_region[..., -1]              # [B, P]
+    # What a pipeline's WAN total, bias and eligibility depend on besides
+    # the request: its stream, locality weight, outage state, region path
+    # and KV capacity (its smallest), and under faults its machines.
+    pipe_kv = machines["kv_tokens"][pl].min(axis=2)         # [B, P]
+    online = np.all(m_region != offs[:, None, None], axis=2)
+    lw = np.asarray(axes["locality_weight"], np.float64)
+    per_cell = np.stack([stream, lw.view(np.int64)], axis=-1)   # [B, 2]
+    key = np.concatenate(
+        [np.broadcast_to(per_cell[:, None], (b, n_pipes, 2)),
+         online[..., None], m_region, pipe_kv[..., None]]
+        + ([pl] if faulted else []), axis=-1).reshape(b * n_pipes, -1)
+    first, combo = _unique_rows(key)
+    cu = key[first, 0]                   # each combination's stream, sorted
+    rank = np.arange(len(first)) - np.searchsorted(cu, cu)
+    width = int(rank.max()) + 1          # combination columns per field
+    fields = ("wan", "bias", "eligible") + (
+        ("base_eligible",) if faulted else ())
+    table = np.empty(requests["submit"].shape + (c0 + len(fields) * width,))
+    for name, block in blocks.items():
+        table[..., at[name]:at[name] + block.shape[-1]] = block
+    j = np.arange(table.shape[1])
+    hop = table[cu[:, None, None], j[:, None],
+                hop_cols.reshape(-1, n_stages)[first][:, None, :]]
+    wan = hop.sum(axis=-1) + table[cu[:, None], j,
+                                   tail_cols.ravel()[first][:, None]]
+    kv_need = requests["kv_need"][cu]                       # [n, J]
+    kv_cap = pipe_kv.ravel()[first][:, None]
+    # KV-cache occupancy: hard eligibility against the pipeline's smallest
+    # capacity, plus a precomputed pressure bias toward VRAM headroom.
+    vals = dict(wan=wan, bias=(
+        (lw[first // n_pipes] - 1.0)[:, None] * wan
+        + float(kv_penalty_s) * (kv_need.astype(np.float64)
+                                 / kv_cap.astype(np.float64))),
+        eligible=(kv_need <= kv_cap) & online.ravel()[first][:, None])
+    windows = None
+    if faulted:
+        down, windows = _fault_view(plan, requests["submit"], regions,
+                                    int(n_regions))
+        pipe_up = ~np.any(down[cu[:, None, None], j[:, None],
+                               pl.reshape(-1, n_stages)[first][:, None, :]],
+                          axis=-1)
+        vals["base_eligible"] = vals["eligible"]
+        vals["eligible"] = (vals["base_eligible"] & pipe_up
+                            & ~requests["gave_up"][cu])
+    combo_cols = {}
+    for k, name in enumerate(fields):
+        lo = c0 + k * width
+        table[cu[:, None], j, (lo + rank)[:, None]] = vals[name]
+        combo_cols[name] = (lo + rank[combo]).reshape(b, n_pipes)
+    combo_cols.setdefault("base_eligible", combo_cols["eligible"])
+    parts = dict(submit=np.full((b, 1), at["submit"]),
+                 svc=(at["svc"] + pl).reshape(b, -1),
+                 hop=hop_cols.reshape(b, -1), tail=tail_cols,
+                 first_extra=at["first_extra"] + pl[..., -1],
+                 kv_need=np.full((b, 1), at["kv_need"]), **combo_cols)
+    cols = np.concatenate(
+        [parts[name] for name in packed_layout(n_pipes, n_stages)], axis=1)
+    return LLMServeBatch(
+        table=table, stream=stream, cols=cols, placement=pl,
+        requests=requests, n_machines=n_machines,
+        slo_ttft_s=float(slo_ttft_s), timeout_s=float(timeout_s),
+        windows=windows), b
 
 
 def empty_llmserve_outputs(n_machines: int, faulted: bool = False

@@ -13,10 +13,13 @@ inside the ``sweep`` span of its ``run_sweep`` call:
                         (``report_fields()``, ``dispatches``)
 ``sweep.validate``      ``validate_scenario_params``
 ``sweep.prepare``       the engine's host build of the batch (``prepare``)
-``sweep.prepare.build`` llmserve: the per-cell routing tables; power:
-                        the demand traces, the fleet and its power tables
-``sweep.prepare.pack``  llmserve: packing them into the lane params;
-                        power: the per-lane copies of the params
+``sweep.prepare.build`` llmserve: one routing table per distinct request
+                        stream, stat ``workloads`` (the streams drawn);
+                        power: the demand traces, the fleet and its power
+                        tables
+``sweep.prepare.pack``  llmserve: each cell's columns gathered into the
+                        lane params; power: the per-lane copies of the
+                        params
 ``sweep.stage``         gathering lane inputs on the host (chunk gather,
                         state prototype, resident batch, refill rows)
 ``sweep.dispatch``      one executable call: argument staging, copies of

@@ -29,8 +29,9 @@ import numpy as np
 
 from . import f64bits
 from .faults import FaultPlan, RetryPolicy
-from .llmserve import build_cells, empty_llmserve_outputs, summarize
-from .spans import span
+from .llmserve import (LLMServeBatch, build_cells, empty_llmserve_outputs,
+                       packed_layout, summarize)
+from .spans import span, tracing
 from .vec_engine import BatchPlan, Done, Loop, VecEngine, make_batch_entry
 
 
@@ -51,45 +52,32 @@ class _Statics(NamedTuple):
 
 class _Params(NamedTuple):
     """The routing tables the compiled loop reads, packed row-per-request
-    (cell axis first); the remaining per-cell arrays stay host-side for
-    :func:`summarize`.
+    (cell axis first); :func:`summarize` reads the rest from the batch's
+    request tables on the host.
 
     Packing everything the body needs for request ``it`` into one
     ``[J, K]`` tensor turns the loop's per-iteration table access into a
     *single* dynamic slice (instead of eight separate gathers across
     eight operands) — measurably faster on CPU where per-op dispatch
     dominates this small body, and bit-preserving: the doubles are the
-    same, only their storage layout changes.  Layout per row (see
-    :func:`_pack_cells`): ``submit | svc[P·S] | hop[P·S] | tail[P] |
-    first_extra[P] | bias[P] | eligible[P] | kv_need``."""
+    same, only their storage layout changes.  Layout per row: the
+    :func:`~repro.core.llmserve.packed_layout` fields up to ``kv_need``."""
     packed: jnp.ndarray       # [J, K]    f64 (or its i64 bits, kv_need an i64)
 
 
-def _pack_cells(cells) -> np.ndarray:
-    """The whole batch's tables as the ``[B, J, K]`` row layout
-    ``_llmserve_build`` unpacks (statically) each iteration — assembled
-    with one stack per field, not one concatenate per cell (host prep is
-    the vec path's wall-clock floor)."""
-    b, j = len(cells), len(cells[0].submit)
-    p, s = cells[0].placement.shape
-    ps = p * s
-    packed = np.empty((b, j, 2 + 2 * ps + 4 * p), np.float64)
-    fields = (
-        (1, lambda c: c.submit[:, None]),
-        (ps, lambda c: c.svc.reshape(j, ps)),
-        (ps, lambda c: c.hop.reshape(j, ps)),
-        (p, lambda c: c.tail),
-        (p, lambda c: c.first_extra),
-        (p, lambda c: c.bias),
-        (p, lambda c: c.eligible),                 # 0.0 / 1.0
-        (1, lambda c: c.kv_need[:, None]),         # exact ≤ 2^53
-    )
-    lo = 0
-    for width, get in fields:
-        view = packed[:, :, lo:lo + width]
-        for i, c in enumerate(cells):
-            view[i] = get(c)
-        lo += width
+def _pack(cells: LLMServeBatch, exact_bits: bool) -> np.ndarray:
+    """The whole batch's ``[B, J, K]`` packed table, each cell's rows
+    gathered from its stream's request table straight into the buffer
+    that becomes the lane params.  On the bit route the doubles travel
+    as their int64 patterns (a view, not a copy) and ``kv_need`` as the
+    integer it is."""
+    k = cells.layout["kv_need"].stop
+    packed = np.empty((len(cells), cells.table.shape[1], k), np.float64)
+    for i in range(len(cells)):
+        cells.columns(i, k, out=packed[i])
+    if exact_bits:
+        packed = f64bits.bits(packed)
+        packed[..., -1] = cells.requests["kv_need"][cells.stream]
     return packed
 
 
@@ -106,7 +94,7 @@ def _llmserve_build(cell, s: _Statics, ops) -> Loop:
     :func:`repro.core.llmserve.route_request`, all pipelines at once."""
     pipes = jnp.arange(s.n_pipelines)
     P, S = s.n_pipelines, s.n_stages
-    ps = P * S
+    f = packed_layout(P, S)
     if s.f64_bits:
         add, maximum, key = f64bits.add, f64bits.maximum, f64bits.key
         inf = jnp.int64(f64bits.INF)
@@ -120,13 +108,13 @@ def _llmserve_build(cell, s: _Statics, ops) -> Loop:
         # splits below are static (fused into the gather by XLA).
         row = cell.packed[it]                         # [K]
         submit = row[0]
-        svc = row[1:1 + ps].reshape(P, S)
-        hop = row[1 + ps:1 + 2 * ps].reshape(P, S)
-        tail = row[1 + 2 * ps:1 + 2 * ps + P]
-        first_extra = row[1 + 2 * ps + P:1 + 2 * ps + 2 * P]
-        bias = row[1 + 2 * ps + 2 * P:1 + 2 * ps + 3 * P]
-        elig = row[1 + 2 * ps + 3 * P:1 + 2 * ps + 4 * P] != 0
-        kv_need = row[-1].astype(c.kv_used.dtype)
+        svc = row[f["svc"]].reshape(P, S)
+        hop = row[f["hop"]].reshape(P, S)
+        tail = row[f["tail"]]
+        first_extra = row[f["first_extra"]]
+        bias = row[f["bias"]]
+        elig = row[f["eligible"]] != 0
+        kv_need = row[-1].astype(c.kv_used.dtype)     # the last field
         # Store-and-forward relay through the pipeline stages (unrolled at
         # trace time): depart(s) = max(free[s], depart(s-1)+hop[s]) + svc[s].
         d = jnp.broadcast_to(submit, (P,))
@@ -183,7 +171,7 @@ def _prepare_llmserve(*, use_pallas: bool, seeds=(0,), n_machines: int = 6,
                       fault_plan: Optional[FaultPlan] = None,
                       retry: Optional[RetryPolicy] = None,
                       timeout_s: float = math.inf, workload=None):
-    with span("sweep.prepare.build"):
+    with span("sweep.prepare.build") as build:
         cells, b = build_cells(
             seeds=seeds, n_machines=n_machines, n_regions=n_regions,
             n_stages=n_stages, n_pipelines=n_pipelines, n_layers=n_layers,
@@ -195,27 +183,21 @@ def _prepare_llmserve(*, use_pallas: bool, seeds=(0,), n_machines: int = 6,
             prompt_tokens=prompt_tokens, decode_tokens=decode_tokens,
             fault_plan=fault_plan, retry=retry, timeout_s=timeout_s,
             workload=workload)
+        if b and tracing():                # distinct request streams drawn
+            build.set_metadata(workloads=len(cells.table))
     if b == 0:
         return Done(empty_llmserve_outputs(
             int(n_machines), faulted=fault_plan is not None
             or math.isfinite(timeout_s)))
-    fx = cells[0].fx
     exact_bits = not f64bits.native()
     with span("sweep.prepare.pack"):
-        packed = _pack_cells(cells)
-        if exact_bits:
-            kv_need = packed[..., -1].astype(np.int64)
-            packed = f64bits.bits(packed)
-            packed[..., -1] = kv_need
-    params = _Params(packed=packed)
-    n_pipes, n_st = cells[0].placement.shape
-    n_requests = len(cells[0].submit)  # an injected workload sets its own
-    # Every lane routes exactly n_requests requests: nothing to bucket.
-    return BatchPlan(params,
-                     _Statics(int(n_requests), int(n_pipes), int(n_st),
-                              bool(use_pallas),
-                              timeout=(fx.timeout_s if fx
-                                       else math.inf),
+        packed = _pack(cells, exact_bits)
+    n_pipes, n_st = cells.placement.shape[1:]
+    # Every lane routes exactly n_requests requests (an injected workload
+    # sets its own): nothing to bucket.
+    return BatchPlan(_Params(packed=packed),
+                     _Statics(packed.shape[1], int(n_pipes), int(n_st),
+                              bool(use_pallas), timeout=cells.timeout_s,
                               f64_bits=exact_bits),
                      finalize=lambda out: summarize(
                          _doubles(out) if exact_bits else out, cells))

@@ -148,6 +148,31 @@ def test_power_sweep_counters_from_shapes(traced_power):
     assert sum(p[0] == "sweep.dispatch" for p in found) == rep.dispatches
 
 
+_STREAM = np.random.default_rng(3)
+# case: (sweep params, distinct request streams the build draws)
+STREAMS = {
+    "tiled": (dict(seeds=np.tile([11, 12, 13, 14], 64), n_requests=8), 4),
+    "distinct": (PARAMS, 8),
+    "workload": (dict(seeds=np.arange(3), workload=dict(
+        submit=np.sort(_STREAM.uniform(0, 10, 12)),
+        src=_STREAM.integers(0, 3, 12, np.int32),
+        prompt_tok=_STREAM.integers(64, 1024, 12),
+        decode_tok=_STREAM.integers(16, 512, 12),
+        online=np.arange(12) >= 3)), 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STREAMS))
+def test_build_span_counts_request_streams(case, tmp_path):
+    """``sweep.prepare.build`` carries the number of distinct request
+    streams drawn: one per scenario seed a population shares, one a cell
+    with distinct seeds, one for an injected workload."""
+    params, want = STREAMS[case]
+    _, found = _traced(tmp_path, SweepConfig(), params=params)
+    (_, _, _, stats), = [p for p in found if p[0] == "sweep.prepare.build"]
+    assert stats["workloads"] == want
+
+
 @pytest.mark.parametrize("path", sorted(CONFIGS))
 def test_profiler_off_changes_nothing(path, tmp_path, monkeypatch):
     config = CONFIGS[path]
