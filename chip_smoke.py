@@ -16,8 +16,9 @@ kind are compared with the ``oo`` reference on the host: every output of
 llmserve (exact on the chip), every integer and bool output of the rest.
 
 Four chips: the same power cells with ``devices=4`` through the chunked
-executor (``pmap``) and the compacting scheduler (``shard_map``), each
-compared bit for bit with a ``devices=1`` run in this process.
+executor and the compacting scheduler, both ``shard_map`` over the four
+chips, each compared bit for bit with a ``devices=1`` run in this
+process.
 
 Every phase prints its wall and compile seconds (one unwarmed run each —
 not metrics).  The last line of standard output is
@@ -255,17 +256,18 @@ def phase_four_chips(run_sweep, SweepConfig):
                     config=SweepConfig(devices=1)).outputs
     four = run_sweep("power_batch", power_params(seeds),
                      config=SweepConfig(devices=4))
-    check(four.report.devices == 4, f"pmap ran on {four.report.devices}")
-    compare("power devices=4 (pmap) vs devices=1", four.outputs, one,
-            keys=sorted(one))
+    check(four.report.devices == 4 and four.report.sharding == "shard_map",
+          f"chunked ran as {four.report_fields()}")
+    compare("power devices=4 chunked (shard_map) vs devices=1", four.outputs,
+            one, keys=sorted(one))
     comp = run_sweep("power_batch", power_params(seeds),
                      config=SweepConfig(devices=4, compact=True))
     check(comp.report.devices == 4 and comp.report.sharding == "shard_map",
           f"compact ran as {comp.report_fields()}")
     compare("power devices=4 compact (shard_map) vs devices=1",
             comp.outputs, one, keys=sorted(one))
-    print(f"  pmap report: {four.report_fields()}")
-    print(f"  shard_map report: {comp.report_fields()}")
+    print(f"  chunked report: {four.report_fields()}")
+    print(f"  compact report: {comp.report_fields()}")
 
 
 def main(argv=None) -> int:

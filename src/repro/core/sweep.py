@@ -25,10 +25,10 @@ driver's host-looped cell batches):
     undone on output; per-lane results are unchanged — only co-residency
     changes.
   * **device sharding** — each chunk's lanes are split across
-    ``jax.devices()`` via ``jax.pmap`` (cells padded to a device multiple)
-    or, with ``sharding="shard_map"``, via a jitted ``shard_map`` over a
-    1-D lane mesh (the multi-process-ready peer path), with a clean
-    single-device ``jit`` fallback; results are bit-identical every way.
+    ``jax.devices()`` by a jitted ``shard_map`` over a 1-D lane mesh
+    (:func:`lanes_sharding`; chunks padded to a device multiple), with a
+    plain single-device ``jit`` otherwise; results are bit-identical
+    either way.
   * **lane compaction** — :func:`compact_sweep` keeps a fixed-size dense
     resident batch and retires/refills lanes mid-flight from a host work
     queue, streaming finished cells to an ``on_chunk`` consumer; device
@@ -99,8 +99,8 @@ class SweepReport:
     # using predicted_cost as the iteration proxy — the gap between this
     # and the observed fraction is the cost model's error.
     active_lane_fraction_predicted: Optional[float] = None
-    # Multi-device executor flavour ("pmap" or "shard_map"); None when the
-    # dispatch ran on a single device.
+    # Multi-device executor ("shard_map"); None when the dispatch ran on a
+    # single device.
     sharding: Optional[str] = None
     # Compacting-scheduler accounting (``compact_sweep``): lanes retired
     # mid-flight, lanes refilled from the work queue, compiled segments
@@ -179,7 +179,6 @@ class SweepConfig:
       * ``segment_iters`` — compact-mode per-segment iteration budget;
       * ``devices`` — ``None``/"auto" = all local, int n = first n, or an
         explicit placement list;
-      * ``sharding`` — multi-device executor, ``"pmap"`` or ``"shard_map"``;
       * ``on_chunk`` / ``progress`` — streaming consumers;
       * ``precision`` — ``"exact"`` (bit-identical f64) or ``"fast"`` (f32
         loop) where the engine offers the opt-in; ``None`` defers to the
@@ -201,7 +200,6 @@ class SweepConfig:
     chunk_size: Optional[int] = None
     segment_iters: Optional[int] = None
     devices: Any = None
-    sharding: Optional[str] = None
     on_chunk: Optional[Callable] = None
     progress: Optional[Callable] = None
     precision: Optional[str] = None
@@ -210,10 +208,6 @@ class SweepConfig:
     quarantine: bool = False
 
     def __post_init__(self):
-        if self.sharding not in (None, "pmap", "shard_map"):
-            raise ValueError(
-                f"sharding must be None, 'pmap' or 'shard_map': "
-                f"{self.sharding!r}")
         if self.precision not in (None, "exact", "fast"):
             raise ValueError(
                 f"precision must be None, 'exact' or 'fast': "
@@ -315,30 +309,24 @@ def lanes_sharding(devices: Sequence[Any]):
 
 
 @functools.lru_cache(maxsize=64)
-def _executor(fn: Callable, devices: tuple, donate: bool,
-              sharding: str = "pmap") -> Callable:
+def _executor(fn: Callable, devices: tuple, donate: bool) -> Callable:
     """Compiled dispatcher for one (engine fn, device placement) pair.
 
     ``fn`` takes a single params pytree with a leading lane axis; the
     engines hand us a per-statics-cached callable so this cache keys on a
-    stable object.  Multi-device wraps either in ``pmap`` over exactly the
-    given devices (an explicit ``devices=`` list is a *placement*, not just
-    a count) or — ``sharding="shard_map"`` — in a jitted ``shard_map`` over
-    a 1-D ``lanes`` mesh, the multi-process-ready peer path (the lane axis
-    stays flat; no per-device fold).  All paths donate the chunk's input
-    buffers when asked.
+    stable object.  Multi-device wraps it in a jitted ``shard_map`` over
+    the 1-D ``lanes`` mesh of exactly the given devices (an explicit
+    ``devices=`` list is a *placement*, not just a count); the lane axis
+    stays flat.  Both paths donate the chunk's input buffers when asked.
     """
     import jax
     donate_argnums = (0,) if donate else ()
     if len(devices) > 1:
-        if sharding == "shard_map":
-            sh = lanes_sharding(devices)
-            # check_vma=False: lanes are independent, nothing is replicated.
-            lanes = jax.shard_map(fn, mesh=sh.mesh, in_specs=(sh.spec,),
-                                  out_specs=sh.spec, check_vma=False)
-            return jax.jit(lanes, donate_argnums=donate_argnums)
-        return jax.pmap(fn, devices=list(devices),
-                        donate_argnums=donate_argnums)
+        sh = lanes_sharding(devices)
+        # check_vma=False: lanes are independent, nothing is replicated.
+        lanes = jax.shard_map(fn, mesh=sh.mesh, in_specs=(sh.spec,),
+                              out_specs=sh.spec, check_vma=False)
+        return jax.jit(lanes, donate_argnums=donate_argnums)
     jitted = jax.jit(fn, donate_argnums=donate_argnums)
     if devices[0] == jax.devices()[0]:
         return jitted                       # default placement: nothing to do
@@ -364,22 +352,8 @@ def _host_bytes(*trees) -> int:
                if isinstance(leaf, np.ndarray))
 
 
-def _dispatch(executor, chunk_params, n_devices: int, fold: bool = True):
-    """Run one chunk, sharding its lanes over devices when there are >1.
-
-    ``pmap`` needs the lane axis folded into ``[device, lane/device]``;
-    a ``shard_map`` executor (``fold=False``) takes the flat lane axis.
-    """
-    import jax
-    if n_devices > 1 and fold:
-        def _fold(leaf):
-            per = leaf.shape[0] // n_devices
-            return leaf.reshape((n_devices, per) + leaf.shape[1:])
-        with span("sweep.dispatch"):
-            out = executor(jax.tree_util.tree_map(_fold, chunk_params))
-        with span("sweep.wait"):
-            return {k: np.asarray(v).reshape((-1,) + np.asarray(v).shape[2:])
-                    for k, v in out.items()}
+def _dispatch(executor, chunk_params):
+    """Run one chunk and bring its outputs back to the host."""
     with span("sweep.dispatch"):
         out = executor(chunk_params)
     with span("sweep.wait"):
@@ -392,7 +366,6 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
                   predicted_cost=None,
                   donate: bool = True,
                   iterations_key: str = "iterations",
-                  sharding: str = "pmap",
                   on_chunk: Optional[Callable] = None,
                   ):
     """Execute a vmapped simulation over its cell axis in scheduled chunks.
@@ -411,17 +384,14 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
     ``predicted_cost`` shows divergence); ``devices=None`` uses all local
     devices (an explicit list is honored as the placement).
     ``predicted_cost`` (one float per cell) buckets cells by predicted
-    length so short lanes don't idle behind long ones.  ``sharding``
-    selects the multi-device executor (``"pmap"`` or ``"shard_map"``) —
-    both bit-identical to single-device dispatch.  ``on_chunk(cells,
+    length so short lanes don't idle behind long ones.  Several devices
+    split each chunk's lanes by ``shard_map``, bit-identical to
+    single-device dispatch.  ``on_chunk(cells,
     outputs)`` streams each finished chunk to the consumer as it completes
     (original cell indices + that chunk's raw output dict) instead of
     making it wait for the monolithic return.
     """
     import jax
-    if sharding not in ("pmap", "shard_map"):
-        raise ValueError(
-            f"sharding must be 'pmap' or 'shard_map': {sharding!r}")
     leaves = jax.tree_util.tree_leaves(params)
     if not leaves:
         raise ValueError("execute_sweep: params pytree has no array leaves")
@@ -430,7 +400,7 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
     if n_cells == 0:
         # Degenerate grid: one empty dispatch preserves the monolithic
         # contract (empty per-key outputs) instead of crashing.
-        out = _dispatch(_executor(fn, devs[:1], donate), params, 1)
+        out = _dispatch(_executor(fn, devs[:1], donate), params)
         return out, SweepReport(
             n_cells=0, chunk_size=0, n_chunks=0, devices=1, bucketed=False,
             donated=donate)
@@ -452,8 +422,7 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
     else:
         order = np.arange(n_cells)
 
-    fold = sharding != "shard_map"
-    executor = _executor(fn, devs, donate, sharding)
+    executor = _executor(fn, devs, donate)
     chunks, chunk_meta = [], []
     h2d = uploads = 0
     with warnings.catch_warnings():
@@ -474,7 +443,7 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
                 chunk_params = _take(params, idx)
             h2d += _host_bytes(chunk_params)
             uploads += 1
-            out = _dispatch(executor, chunk_params, n_dev, fold)
+            out = _dispatch(executor, chunk_params)
             chunks.append({k: v[:real] for k, v in out.items()})
             chunk_meta.append(real)
             if on_chunk is not None:
@@ -514,7 +483,7 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
         active_lane_fraction_monolithic=frac_mono,
         lane_iterations=lane_iters,
         active_lane_fraction_predicted=frac_pred,
-        sharding=sharding if n_dev > 1 else None, h2d_bytes=h2d,
+        sharding="shard_map" if n_dev > 1 else None, h2d_bytes=h2d,
         param_uploads=uploads)
     return outputs, report
 

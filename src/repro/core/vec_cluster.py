@@ -31,9 +31,8 @@ from ..kernels.ops import masked_argmax
 from .backend import SimBackend, scenario
 from .cluster import FleetConfig, RunStats, StepCost, fleet_fault_windows
 from .faults import FaultPlan
-from .vec_engine import (BatchPlan, Done, Loop, StepSpec, VecEngine,
-                         body_from_step, make_batch_entry, resolve_precision,
-                         x64)
+from .vec_engine import (BatchPlan, Done, Loop, VecEngine, make_batch_entry,
+                         resolve_precision, x64)
 
 STALL_RETRY_S = 60.0          # matches FleetSim's stall-retry cadence
 
@@ -174,12 +173,9 @@ def _fleet_build(args, s: _Statics, ops) -> Loop:
     def cond(c: _Carry, it):
         return (c.step < params.total_steps) & (c.t < params.max_wall_s)
 
-    def step(c: _Carry, sl, it) -> _Carry:
-        # Fusion-eligible step (StepSpec contract): the whole body as a
-        # pure function of (state, stream slices, it).  The fleet has no
-        # per-iteration stream tables — everything per-step (RNG draws,
-        # schedule lookups) derives from ``it`` — so ``sl`` is empty.
-        del sl
+    def body(c: _Carry, it) -> _Carry:
+        # The fleet has no per-iteration stream tables: everything per-step
+        # (RNG draws, schedule lookups) derives from ``it``.
         # Current renewal round = number of fully completed outages; the
         # count form needs no carried pointer and is always caught up.
         ended = jnp.sum(fail_start + params.repair_s <= c.t, axis=1,
@@ -376,15 +372,12 @@ def _fleet_build(args, s: _Statics, ops) -> Loop:
         watch_from=jnp.asarray(-jnp.inf, fail_start.dtype),
         failures=zi, restarts=zi, evictions=zi,
         lost_steps=zf, stall_s=zf, ckpt_s=zf)
-    spec = StepSpec(step=step)
-    # The loop is a genuine while-loop (steps/wall-clock race ⇒ data-
-    # dependent cond), so fusion runs one kernel per iteration
-    # (fused_step_body) with the cond outside — never a whole-loop scan.
-    return Loop(init=init, cond=cond, body=body_from_step(spec),
-                finalize=finalize, step_kernel=spec)
+    # A genuine while-loop: steps and wall clock race, so the cond is
+    # data-dependent and no trip_count applies.
+    return Loop(init=init, cond=cond, body=body, finalize=finalize)
 
 
-FLEET_ENGINE = VecEngine("fleet_batch", _fleet_build, step_fusable=True)
+FLEET_ENGINE = VecEngine("fleet_batch", _fleet_build)
 
 
 def _predicted_iters(params: _Params, n_total: int) -> np.ndarray:

@@ -63,7 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.ops import MaskedOps, pallas_native, resolve_use_pallas
+from ..kernels.ops import MaskedOps, resolve_use_pallas
 from .backend import scenario
 from .spans import span
 from .sweep import (MIN_CHUNK, SweepReport, compact_sweep, execute_sweep,
@@ -79,41 +79,6 @@ def x64():
     golden fixtures recorded from them) are that stream's draws."""
     with jax.enable_x64(True), jax.threefry_partitionable(False):
         yield
-
-
-class StepSpec(NamedTuple):
-    """An engine's fusion-eligible step declaration (``Loop.step_kernel``).
-
-    ``step(state, stream_slices, it) -> state`` is the *whole* loop body
-    as a pure function of the carried state pytree, this iteration's
-    stream slices, and the driver's int32 counter ``it``.  ``streams`` is
-    a pytree of per-iteration input arrays with the iteration axis first
-    (``[T, ...]``) — empty for engines whose body needs no per-step table
-    (the jnp path reads ``leaf[it]``; the scan kernel blocks the leaf
-    per-step so Pallas prefetches it HBM→VMEM ahead of the compute).
-
-    The contract (what a ``Loop`` must declare for fusion eligibility):
-    ``step`` must be the single source of truth for the body — the jnp
-    ``Loop.body`` must be :func:`body_from_step` of the same spec — and
-    must hold the substrate's SoA invariants: fixed-shape state leaves,
-    no data-dependent shapes, and any nested masked reductions in plain
-    jnp (``MaskedOps(False)`` — a nested ``pallas_call`` cannot lower
-    from inside a kernel; the driver hands fused builds a jnp ``ops``).
-    The kernels themselves live in :mod:`repro.kernels.step`.
-    """
-
-    step: Callable[[Any, Any, Any], Any]
-    streams: Any = ()
-
-
-def body_from_step(spec: StepSpec) -> Callable[[Any, Any], Any]:
-    """The canonical jnp ``Loop.body`` for a :class:`StepSpec`: slice each
-    stream at ``it`` and apply ``step``.  Engines derive their body from
-    this so the fused and jnp paths share one op sequence."""
-    def body(state, it):
-        sl = jax.tree_util.tree_map(lambda a: a[it], spec.streams)
-        return spec.step(state, sl, it)
-    return body
 
 
 class Loop(NamedTuple):
@@ -133,13 +98,6 @@ class Loop(NamedTuple):
     lanes provably run the same count.  The body sequence is identical
     either way, so outputs stay bit-exact; the compacting scheduler keeps
     the while-loop form (its lanes genuinely pause mid-stream).
-
-    ``step_kernel`` (optional) declares the body fusion-eligible: a
-    :class:`StepSpec` whose ``step`` the engine also
-    derived its jnp ``body`` from (``body_from_step``), so the monolithic
-    driver may execute the whole iteration as one Pallas kernel
-    (``fused_step_body``) — or, with ``trip_count`` set, the whole loop as
-    one ``pallas_call`` (``fused_scan``) — with bit-identical outputs.
     """
 
     init: Any
@@ -147,71 +105,35 @@ class Loop(NamedTuple):
     body: Callable[[Any, Any], Any]
     finalize: Callable[[Any, Any], Dict[str, Any]]
     trip_count: Optional[int] = None
-    step_kernel: Optional[StepSpec] = None
 
 
 @dataclass(frozen=True)
 class VecEngine:
-    """A scenario kind as a declarative SoA event-loop definition.
-
-    ``step_fusable`` promises that the engine's ``build`` returns a
-    ``Loop.step_kernel`` spec whenever fusion could apply — the driver
-    must know *before* calling ``build`` whether the whole body becomes
-    the kernel, because the ``MaskedOps`` it hands in must then stay on
-    the plain-jnp path (a nested ``pallas_call`` can't lower from inside
-    the step kernel).
-    """
+    """A scenario kind as a declarative SoA event-loop definition."""
 
     kind: str
     build: Callable[[Any, Any, MaskedOps], Loop]
-    step_fusable: bool = False
 
 
 def run_one(engine: VecEngine, params: Any, statics: Any) -> Dict[str, Any]:
-    """One cell, start to finish, as a single ``lax.while_loop``."""
-    use_pallas = bool(getattr(statics, "use_pallas", False))
-    # Whole-body fusion supersedes the per-reduction kernel: when the step
-    # itself is the pallas_call, the masked reductions inside it must be
-    # plain jnp (they run *inside* the kernel either way).
-    fuse = use_pallas and engine.step_fusable
-    ops = MaskedOps(use_pallas and not fuse)
+    """One cell, start to finish: a ``fori_loop`` when the loop declares
+    ``trip_count``, a ``lax.while_loop`` otherwise."""
+    ops = MaskedOps(bool(getattr(statics, "use_pallas", False)))
     loop = engine.build(params, statics, ops)
-    spec = loop.step_kernel if fuse else None
-    interpret = not pallas_native()
-
-    if spec is not None:
-        if not interpret:
-            # See repro.kernels.step: no engine step is built from ops the
-            # TPU lowering implements yet.
-            raise NotImplementedError(
-                f"use_pallas=True: the {engine.kind} step does not lower as "
-                f"a native Pallas kernel on the {jax.default_backend()!r} "
-                f"backend — run it with use_pallas=False")
-        from ..kernels.step import fused_scan, fused_step_body
     if loop.trip_count is not None:
-        if spec is not None:
-            # Whole loop as ONE pallas_call: VMEM-resident state across
-            # grid steps, per-iteration streams prefetched per block.
-            state = fused_scan(spec, loop.init, int(loop.trip_count),
-                               interpret=interpret)
-        else:
-            # Static trip count → fori_loop (lowers to scan): vmap batches
-            # the body directly, with none of while_loop's per-leaf select
-            # masking.
-            state = jax.lax.fori_loop(
-                0, int(loop.trip_count),
-                lambda i, s: loop.body(s, jnp.asarray(i, jnp.int32)),
-                loop.init)
+        # Static trip count → fori_loop (lowers to scan): vmap batches the
+        # body directly, with none of while_loop's per-leaf select masking.
+        state = jax.lax.fori_loop(
+            0, int(loop.trip_count),
+            lambda i, s: loop.body(s, jnp.asarray(i, jnp.int32)),
+            loop.init)
         it = jnp.asarray(int(loop.trip_count), jnp.int32)
     else:
-        step = (fused_step_body(spec, interpret=interpret)
-                if spec is not None else loop.body)
-
         def cond(c):
             return loop.cond(c[0], c[1])
 
         def body(c):
-            return step(c[0], c[1]), c[1] + 1
+            return loop.body(c[0], c[1]), c[1] + 1
 
         state, it = jax.lax.while_loop(cond, body,
                                        (loop.init, jnp.asarray(0, jnp.int32)))
@@ -250,13 +172,10 @@ def _segment_sim(engine: VecEngine, statics: Any, budget: int) -> Callable:
     """vmapped segment body: resume/merge, advance ≤ ``budget`` iterations,
     report termination + finalized outputs.
 
-    The compacting path always runs the jnp ``Loop.body`` — segments
-    pause/resume lanes mid-stream, which the whole-loop ``fused_scan``
-    cannot express, and the per-step fused body buys nothing under the
-    segment budget's extra select masking.  ``use_pallas`` still routes
-    the *reductions* through the next-event kernel here; outputs stay
-    bit-identical to the monolithic (fused or not) run either way.  Its
-    ops carry the scope ``<kind>/segment`` in the profiler's trace.
+    ``use_pallas`` routes the reductions through the next-event kernel,
+    as on the monolithic path; outputs stay bit-identical to the
+    monolithic run either way.  Its ops carry the scope
+    ``<kind>/segment`` in the profiler's trace.
     """
     ops = MaskedOps(bool(getattr(statics, "use_pallas", False)))
 
@@ -450,7 +369,6 @@ def run_compact(engine: VecEngine, plan: BatchPlan, *, chunk_size=None,
 def run_plan(engine: VecEngine, plan, *, chunk_size=None, devices=None,
              donate: bool = True, with_report: bool = False,
              compact: bool = False, segment_iters=None,
-             sharding: Optional[str] = None,
              on_chunk: Optional[Callable] = None,
              progress: Optional[Callable] = None,
              quarantine: bool = False):
@@ -458,11 +376,12 @@ def run_plan(engine: VecEngine, plan, *, chunk_size=None, devices=None,
 
     ``compact=True`` routes through the compacting lane scheduler
     (:func:`run_compact`) — bit-identical outputs, O(chunk) device memory,
-    streaming retires.  Otherwise chunked dispatch (:func:`execute_sweep`)
-    with ``sharding`` selecting the multi-device executor ("pmap" default,
-    "shard_map" peer).  ``on_chunk(cells, raw_outputs)`` streams finished
-    cells on either path; the payload is the engine's *raw* output dict
-    (before ``plan.finalize``), keyed by original cell indices.
+    streaming retires.  Otherwise chunked dispatch (:func:`execute_sweep`).
+    Both split lanes over several devices by ``shard_map`` on the mesh of
+    :func:`repro.core.sweep.lanes_sharding`.  ``on_chunk(cells,
+    raw_outputs)`` streams finished cells on either path; the payload is
+    the engine's *raw* output dict (before ``plan.finalize``), keyed by
+    original cell indices.
     """
     if isinstance(plan, Done):
         out, report = plan.outputs, empty_report(donate)
@@ -480,7 +399,7 @@ def run_plan(engine: VecEngine, plan, *, chunk_size=None, devices=None,
                     batched_sim(engine, plan.statics), plan.params,
                     chunk_size=chunk_size, devices=devices, donate=donate,
                     predicted_cost=plan.predicted_cost,
-                    sharding=sharding or "pmap", on_chunk=on_chunk)
+                    on_chunk=on_chunk)
         if plan.finalize is not None:
             with span("sweep.finalize"):
                 out = plan.finalize(out)
@@ -497,8 +416,7 @@ def make_batch_entry(engine: VecEngine, prepare: Callable, *,
     :class:`BatchPlan` (or :class:`Done`).  The produced entry adds the
     uniform sweep controls (``use_pallas``, ``chunk_size``, ``devices``,
     ``donate``, ``with_report``, ``compact``, ``segment_iters``,
-    ``sharding``, ``on_chunk``, ``progress``, ``quarantine``) to
-    ``prepare``'s own
+    ``on_chunk``, ``progress``, ``quarantine``) to ``prepare``'s own
     signature and is registered as the ``kind`` handler for ``backends``
     (pass ``backends=()`` to skip registration, e.g. when a hand-written
     handler dispatches on input shape first).
@@ -508,7 +426,6 @@ def make_batch_entry(engine: VecEngine, prepare: Callable, *,
     def entry(*args, use_pallas: bool | str = False, chunk_size=None,
               devices=None, donate: bool = True, with_report: bool = False,
               compact: bool = False, segment_iters=None,
-              sharding: Optional[str] = None,
               on_chunk: Optional[Callable] = None,
               progress: Optional[Callable] = None,
               quarantine: bool = False,
@@ -519,8 +436,8 @@ def make_batch_entry(engine: VecEngine, prepare: Callable, *,
         return run_plan(engine, plan, chunk_size=chunk_size, devices=devices,
                         donate=donate, with_report=with_report,
                         compact=compact, segment_iters=segment_iters,
-                        sharding=sharding, on_chunk=on_chunk,
-                        progress=progress, quarantine=quarantine)
+                        on_chunk=on_chunk, progress=progress,
+                        quarantine=quarantine)
 
     entry.__name__ = name or f"simulate_{kind}"
     entry.__qualname__ = entry.__name__

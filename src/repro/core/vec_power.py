@@ -34,6 +34,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, Dict, NamedTuple, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -44,8 +45,7 @@ from .power import (_broadcast_cells, _empty_outputs, _finalize,
                     host_capacities, make_power_fleet, power_fault_table,
                     power_points)
 from .spans import span
-from .vec_engine import (BatchPlan, Done, Loop, StepSpec, VecEngine,
-                         body_from_step, make_batch_entry)
+from .vec_engine import BatchPlan, Done, Loop, VecEngine, make_batch_entry
 
 
 @dataclass(frozen=True)
@@ -130,13 +130,10 @@ def _power_build(params: _Params, s: _Statics, ops) -> Loop:
     """One elastic-datacenter cell: one loop iteration per trace interval
     (the driver's counter ``it`` is the interval index ``k``).
 
-    The body is declared as a fusion-eligible *step* over per-interval
-    streams (the demand trace, and the crash table when faulted): the jnp
-    ``body`` is :func:`~repro.core.vec_engine.body_from_step` of the same
-    step, and the returned ``Loop`` carries ``trip_count`` +
-    ``step_kernel`` so the driver may run the whole trace as one Pallas
-    scan kernel (streams double-buffered HBM→VMEM per interval) with
-    bit-identical outputs.
+    Each iteration slices the per-interval streams (the demand trace, and
+    the crash table when faulted) at ``it`` and applies ``step``.  The
+    returned ``Loop`` carries ``trip_count``, so the driver lowers it to
+    ``fori_loop``.
     """
     H = s.n_hosts
     idx = jnp.arange(H)
@@ -244,16 +241,17 @@ def _power_build(params: _Params, s: _Statics, ops) -> Loop:
                   over_count=jnp.zeros((H,), jnp.int32),
                   unserved=jnp.zeros((H,), params.cap.dtype),
                   migrations=zi, scale_out=zi, scale_in=zi)
-    spec = StepSpec(step=step, streams=streams)
+    def body(c: _Carry, it) -> _Carry:
+        return step(c, jax.tree_util.tree_map(lambda a: a[it], streams), it)
+
     # trip_count: every lane runs exactly n_intervals iterations (the cond
     # is a pure counter check), so the driver lowers to fori_loop/scan —
     # identical body sequence, bit-identical outputs (Loop docstring).
     return Loop(init=init, cond=lambda c, it: it < s.n_intervals,
-                body=body_from_step(spec), finalize=finalize,
-                trip_count=s.n_intervals, step_kernel=spec)
+                body=body, finalize=finalize, trip_count=s.n_intervals)
 
 
-POWER_ENGINE = VecEngine("power_batch", _power_build, step_fusable=True)
+POWER_ENGINE = VecEngine("power_batch", _power_build)
 
 
 def _prepare_power(*, use_pallas: bool, seeds: Sequence[int] | np.ndarray = (0,),

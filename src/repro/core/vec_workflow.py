@@ -378,8 +378,8 @@ def run_case_study_vec(*, virt: str = "V", placement: str = "II",
     broadcasts them to a cell grid and returns a list of results computed in
     **one** compiled vmap call (the whole Figure 5 / Table 3 grid at once),
     scheduled by the sweep layer (``chunk_size``/``devices`` plus any
-    further sweep controls — ``compact``, ``segment_iters``, ``sharding``,
-    ``on_chunk`` — forwarded to :func:`simulate_specs`;
+    further sweep controls — ``compact``, ``segment_iters``, ``on_chunk``
+    — forwarded to :func:`simulate_specs`;
     ``with_report=True`` additionally returns the ``SweepReport``).
     """
     from .case_study import PAYLOAD_BIG, CaseStudyResult

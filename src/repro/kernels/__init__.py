@@ -4,8 +4,6 @@
 #
 # Kernels in the tree (see ARCHITECTURE.md "Kernels"):
 #   next_event.py — fused masked (min, argmin) next-event reduction
-#   step.py       — whole VecEngine loop iterations as single kernels
-#                   (per-step fused body + static-trip-count scan)
 #   flash_attention.py / rwkv6_scan.py — model-stack kernels
 #   ops.py        — public adapters + the use_pallas resolution switch
 #

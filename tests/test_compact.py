@@ -8,7 +8,8 @@ every refill edge case the host scheduler has (queue drains mid-chunk, all
 lanes finishing on the same step, single-lane grids, refill under LPT
 bucketing), the streaming ``on_chunk``/``progress`` consumer APIs, the
 report's refill/retire/peak-lane accounting, and 2-device ``shard_map``
-parity in a subprocess (mirroring the pmap test in ``test_sweep.py``).
+parity in a subprocess (mirroring the chunked-path test in
+``test_sweep.py``).
 """
 import os
 import subprocess
@@ -298,11 +299,6 @@ def test_identity_compact_leaves_caller_params_unchanged(quarantine):
 
 # -- sharding ------------------------------------------------------------------
 
-def test_execute_sweep_rejects_unknown_sharding():
-    with pytest.raises(ValueError, match="sharding"):
-        _fleet(sharding="spmd")
-
-
 _SUBPROC_PRELUDE = f"""
 import numpy as np
 from repro.core.vec_cluster import simulate_fleet_batch
@@ -334,13 +330,11 @@ def _run_two_device(code: str) -> str:
 
 def test_shard_map_two_device_parity(mono):
     """shard_map sharding over 2 forced host devices reproduces the
-    1-device bits — on the chunked path and the compacting path.  Mirrors
-    the pmap parity test; needs a fresh process (XLA device count is fixed
-    at backend init)."""
+    1-device bits — on the chunked path and the compacting path.  Needs a
+    fresh process (XLA device count is fixed at backend init)."""
     stdout = _run_two_device("""
 out, rep = simulate_fleet_batch(cost, cfg, 60, chunk_size=16,
-                                sharding="shard_map", with_report=True,
-                                **kw)
+                                with_report=True, **kw)
 assert rep.devices == 2 and rep.sharding == "shard_map", rep
 print(out["wallclock_s"].tobytes().hex())
 cout, crep = simulate_fleet_batch(cost, cfg, 60, compact=True,

@@ -162,9 +162,81 @@ def test_native_f64_reduction_raises(native):
 
 
 def test_native_fused_step_raises(native):
-    """No engine step lowers as a native kernel yet: power asks for the
-    whole-loop scan kernel and is told to run use_pallas=False."""
+    """Power's host picks reduce f64 watts: the native next-event kernel
+    refuses them when the loop is traced and tells the caller to run
+    use_pallas=False."""
     from repro.core.vec_power import simulate_power_batch
     with pytest.raises(NotImplementedError, match="use_pallas=False"):
         simulate_power_batch(seeds=[0, 1, 2], n_hosts=5, n_vms=9,
                              n_samples=7, use_pallas=True)
+
+
+# -- the next-event route inside the engines under use_pallas="force" ---------
+#
+# "force" routes every masked reduction of fleet and power through the
+# interpret-mode next-event kernel, on the monolithic loop and in the
+# compacting scheduler's segments, and every output must be bit-identical
+# to the plain jnp path, so golden fixtures cannot churn.
+
+def _assert_outputs_equal(a, b):
+    assert set(a) == set(b)
+    for k in sorted(a):
+        x, y = np.asarray(a[k]), np.asarray(b[k])
+        assert x.dtype == y.dtype, f"{k}: dtype {x.dtype} vs {y.dtype}"
+        assert np.array_equal(x, y), f"{k}: kernel route drifted"
+
+
+# Compacting: 2 resident lanes and short segments, so lanes pause, resume
+# and (fleet) refill with the kernel route inside the segment step.
+_SCHEDULES = {"monolithic": {},
+              "compact": dict(compact=True, chunk_size=2, segment_iters=5)}
+
+
+@pytest.mark.parametrize("schedule", sorted(_SCHEDULES))
+def test_differential_fleet_force_parity(schedule):
+    """Fleet (while-loop engine): stochastic config with stragglers,
+    eviction, degradation and failures on."""
+    from repro.core.cluster import FleetConfig, StepCost
+    from repro.core.vec_cluster import simulate_fleet_batch
+    cost = StepCost(compute_s=1.0, memory_s=0.4, collective_s=0.3,
+                    overlap_collective=0.5)
+    cfg = FleetConfig(n_nodes=4, n_spares=1, straggler_sigma=0.25,
+                      mtbf_hours_node=4.0)
+    kw = dict(seeds=[0, 1, 2], max_wallclock_s=20_000.0)
+    a = simulate_fleet_batch(cost, cfg, 40, use_pallas=False, **kw)
+    b = simulate_fleet_batch(cost, cfg, 40, use_pallas="force",
+                             **_SCHEDULES[schedule], **kw)
+    _assert_outputs_equal(a, b)
+
+
+@pytest.mark.parametrize("schedule", sorted(_SCHEDULES))
+def test_differential_power_force_parity(schedule):
+    """Power (static-trip-count engine), clean and faulted (the crash
+    table adds the keep-alive reduction)."""
+    from repro.core.faults import FaultEvent, FaultPlan
+    from repro.core.vec_power import simulate_power_batch
+    kw = dict(seeds=[0, 1], n_hosts=4, n_vms=8, n_samples=16)
+    sched = _SCHEDULES[schedule]
+    a = simulate_power_batch(use_pallas=False, **kw)
+    b = simulate_power_batch(use_pallas="force", **sched, **kw)
+    _assert_outputs_equal(a, b)
+    plan = FaultPlan([FaultEvent("node", 600.0, 1800.0, target=1)])
+    a = simulate_power_batch(use_pallas=False, fault_plan=plan, **kw)
+    b = simulate_power_batch(use_pallas="force", fault_plan=plan, **sched,
+                             **kw)
+    _assert_outputs_equal(a, b)
+
+
+@pytest.mark.parametrize("schedule", sorted(_SCHEDULES))
+def test_power_force_matches_oo_bit_exact(schedule):
+    """Transitivity check the differential suite relies on: the kernel
+    route equals vec-plain, which equals the OO reference — so it must
+    equal OO directly too (the strongest end-to-end statement)."""
+    from repro.core.backend import run_scenario
+    from repro.core.vec_power import simulate_power_batch
+    kw = dict(seeds=[3], n_hosts=4, n_vms=8, n_samples=16)
+    oo = run_scenario("power_batch", backend="oo", **kw)
+    forced = simulate_power_batch(use_pallas="force", **_SCHEDULES[schedule],
+                                  **kw)
+    for k in ("energy_wh", "migrations", "sla_s", "final_active"):
+        assert np.array_equal(np.asarray(oo[k]), np.asarray(forced[k])), k

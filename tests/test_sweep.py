@@ -76,8 +76,9 @@ def test_fleet_donation_off_bit_identical():
 
 
 def test_fleet_multi_device_sharded_bit_identical():
-    """pmap sharding over 2 (forced host) devices reproduces the 1-device
-    bits.  Needs a fresh process: XLA device count is fixed at backend init."""
+    """The default multi-device executor (``shard_map`` over the lane mesh)
+    on 2 forced host devices reproduces the 1-device bits.  Needs a fresh
+    process: XLA device count is fixed at backend init."""
     mono = _fleet(chunk_size=B)
     code = f"""
 import numpy as np
@@ -95,7 +96,7 @@ out, rep = simulate_fleet_batch(
     mtbf_hours=np.repeat([200.0, 20.0, 2.0, 0.5], {B // 4}),
     ckpt_every=np.tile([10, 50], {B // 2}),
     chunk_size=16, with_report=True)
-assert rep.devices == 2, rep
+assert rep.devices == 2 and rep.sharding == "shard_map", rep
 print(out["wallclock_s"].tobytes().hex())
 print(out["goodput"].tobytes().hex())
 """

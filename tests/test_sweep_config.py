@@ -43,11 +43,11 @@ def test_config_defaults_round_trip():
 
 def test_config_non_default_round_trip():
     cfg = SweepConfig(compact=True, chunk_size=64, segment_iters=7,
-                      sharding="shard_map", precision="exact",
+                      quarantine=True, precision="exact",
                       use_pallas="force", donate=False)
     kw = cfg.to_kwargs()
     assert kw == dict(compact=True, chunk_size=64, segment_iters=7,
-                      sharding="shard_map", precision="exact",
+                      quarantine=True, precision="exact",
                       use_pallas="force", donate=False)
     assert SweepConfig.from_kwargs(**kw) == cfg
 
@@ -60,8 +60,6 @@ def test_config_replace_is_functional():
 
 
 def test_config_validates_enums_and_bounds():
-    with pytest.raises(ValueError, match="sharding"):
-        SweepConfig(sharding="psum")
     with pytest.raises(ValueError, match="precision"):
         SweepConfig(precision="double")
     with pytest.raises(ValueError, match="chunk_size"):

@@ -149,8 +149,7 @@ def test_plain_path_never_loads_pallas():
             "run_sweep('power_batch', dict(seeds=[0, 1], n_hosts=4, n_vms=8,"
             " n_samples=8))\n"
             "run_sweep('netdc_batch', dict(seeds=[0, 1], n_dcs=3, n_jobs=8))\n"
-            "print(sorted(m for m in sys.modules if 'pallas' in m\n"
-            "             or m == 'repro.kernels.step'))\n")
+            "print(sorted(m for m in sys.modules if 'pallas' in m))\n")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.pathsep.join(sys.path))
     proc = subprocess.run([sys.executable, "-c", code], env=env,
