@@ -227,9 +227,8 @@ class LLMServeBatch:
     distinct there is one stream a cell.
 
     ``batch[i]`` materialises cell ``i``'s :class:`LLMServeCell` (the OO
-    broker and tests read it); :meth:`columns` gathers into a caller's
-    buffer (the vec engine's packed table); :func:`summarize` reads the
-    request tables in place."""
+    broker and tests read it); the vec engine gathers each lane's columns
+    on the device; :func:`summarize` reads the request tables in place."""
     table: np.ndarray         # [U, J, C]  f64 request table per stream
     stream: np.ndarray        # [B]        i64 the stream cell b draws
     cols: np.ndarray          # [B, N]     i64 its columns (packed_layout)
@@ -249,15 +248,8 @@ class LLMServeBatch:
     def layout(self) -> Dict[str, slice]:
         return packed_layout(*self.placement.shape[1:])
 
-    def columns(self, i: int, n: Optional[int] = None, out=None):
-        """Cell ``i``'s first ``n`` columns (all by default), ``[J, n]``."""
-        # mode="clip" lets numpy write `out` unbuffered; no index is out
-        # of range.
-        return np.take(self.table[self.stream[i]], self.cols[i, :n],
-                       axis=1, out=out, mode="clip")
-
     def __getitem__(self, i: int) -> LLMServeCell:
-        row, f = self.columns(i), self.layout
+        row, f = self.table[self.stream[i]][:, self.cols[i]], self.layout
         req = {k: v[self.stream[i]] for k, v in self.requests.items()}
         pl = self.placement[i]
         shape = (row.shape[0],) + pl.shape

@@ -126,6 +126,10 @@ class SweepReport:
     # on the chunked path; on the compacting path one, plus one for each
     # segment that follows a refill.
     param_uploads: int = 0
+    # Bytes of the plan's sweep-wide operand (``shared``) put on the
+    # device: once a sweep, whatever the chunks, segments and refills;
+    # counted in ``h2d_bytes`` too.  0 for a plan without one.
+    shared_bytes: int = 0
 
     @property
     def dispatches(self) -> int:
@@ -155,6 +159,7 @@ class SweepReport:
             peak_lanes=self.peak_lanes, quarantined=self.quarantined,
             retried_segments=self.retried_segments,
             h2d_bytes=self.h2d_bytes, param_uploads=self.param_uploads,
+            shared_bytes=self.shared_bytes,
             observed_active_lane_fraction=(
                 round(self.active_lane_fraction_observed, 4)
                 if self.active_lane_fraction_observed is not None else None),
@@ -308,31 +313,55 @@ def lanes_sharding(devices: Sequence[Any]):
                          PartitionSpec("lanes"))
 
 
+def _put_shared(shared, devices: Sequence[Any]):
+    """A plan's sweep-wide operand put on the device, once a sweep:
+    ``(the executables' trailing arguments, bytes sent)``.  Replicated over
+    the ``lanes`` mesh of several devices, else on the one device (the
+    default one where ``devices`` is empty); ``((), 0)`` without one."""
+    if shared is None:
+        return (), 0
+    import jax
+    if len(devices) > 1:
+        from jax.sharding import NamedSharding, PartitionSpec
+        where = NamedSharding(lanes_sharding(devices).mesh, PartitionSpec())
+    else:
+        where = devices[0] if devices else None
+    with span("sweep.stage"):
+        return (jax.device_put(shared, where),), _host_bytes(shared)
+
+
 @functools.lru_cache(maxsize=64)
-def _executor(fn: Callable, devices: tuple, donate: bool) -> Callable:
+def _executor(fn: Callable, devices: tuple, donate: bool,
+              shared: bool = False) -> Callable:
     """Compiled dispatcher for one (engine fn, device placement) pair.
 
-    ``fn`` takes a single params pytree with a leading lane axis; the
-    engines hand us a per-statics-cached callable so this cache keys on a
-    stable object.  Multi-device wraps it in a jitted ``shard_map`` over
-    the 1-D ``lanes`` mesh of exactly the given devices (an explicit
-    ``devices=`` list is a *placement*, not just a count); the lane axis
-    stays flat.  Both paths donate the chunk's input buffers when asked.
+    ``fn`` takes a single params pytree with a leading lane axis, and with
+    ``shared`` the plan's sweep-wide operand as a second argument, which
+    has none; the engines hand us a per-statics-cached callable so this
+    cache keys on a stable object.  Multi-device wraps it in a jitted
+    ``shard_map`` over the 1-D ``lanes`` mesh of exactly the given devices
+    (an explicit ``devices=`` list is a *placement*, not just a count);
+    the lane axis stays flat and the shared operand is replicated.  Both
+    paths donate the chunk's lane inputs when asked, never the shared
+    operand.
     """
     import jax
     donate_argnums = (0,) if donate else ()
     if len(devices) > 1:
+        from jax.sharding import PartitionSpec
         sh = lanes_sharding(devices)
-        # check_vma=False: lanes are independent, nothing is replicated.
-        lanes = jax.shard_map(fn, mesh=sh.mesh, in_specs=(sh.spec,),
+        specs = (sh.spec, PartitionSpec()) if shared else (sh.spec,)
+        # check_vma=False: lanes are independent; the shared operand is
+        # only read.
+        lanes = jax.shard_map(fn, mesh=sh.mesh, in_specs=specs,
                               out_specs=sh.spec, check_vma=False)
         return jax.jit(lanes, donate_argnums=donate_argnums)
     jitted = jax.jit(fn, donate_argnums=donate_argnums)
     if devices[0] == jax.devices()[0]:
         return jitted                       # default placement: nothing to do
 
-    def on_device(params):
-        return jitted(jax.device_put(params, devices[0]))
+    def on_device(params, *shared_dev):
+        return jitted(jax.device_put(params, devices[0]), *shared_dev)
     return on_device
 
 
@@ -352,10 +381,10 @@ def _host_bytes(*trees) -> int:
                if isinstance(leaf, np.ndarray))
 
 
-def _dispatch(executor, chunk_params):
+def _dispatch(executor, chunk_params, *shared_dev):
     """Run one chunk and bring its outputs back to the host."""
     with span("sweep.dispatch"):
-        out = executor(chunk_params)
+        out = executor(chunk_params, *shared_dev)
     with span("sweep.wait"):
         return {k: np.asarray(v) for k, v in out.items()}
 
@@ -367,6 +396,7 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
                   donate: bool = True,
                   iterations_key: str = "iterations",
                   on_chunk: Optional[Callable] = None,
+                  shared: Any = None,
                   ):
     """Execute a vmapped simulation over its cell axis in scheduled chunks.
 
@@ -390,6 +420,12 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
     outputs)`` streams each finished chunk to the consumer as it completes
     (original cell indices + that chunk's raw output dict) instead of
     making it wait for the monolithic return.
+
+    ``shared`` (optional) is a pytree with no cell axis that every lane
+    reads: ``fn(params, shared)`` then takes it unbatched.  It goes to the
+    device once (replicated over the devices) and rides beside every
+    chunk, padded ones included; it is never gathered, donated or sent
+    again (``SweepReport.shared_bytes``).
     """
     import jax
     leaves = jax.tree_util.tree_leaves(params)
@@ -397,10 +433,13 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
         raise ValueError("execute_sweep: params pytree has no array leaves")
     n_cells = int(np.shape(leaves[0])[0])
     devs = tuple(resolve_devices(devices))
+    has_shared = shared is not None
     if n_cells == 0:
         # Degenerate grid: one empty dispatch preserves the monolithic
         # contract (empty per-key outputs) instead of crashing.
-        out = _dispatch(_executor(fn, devs[:1], donate), params)
+        shared_dev, _ = _put_shared(shared, devs[:1])
+        out = _dispatch(_executor(fn, devs[:1], donate, has_shared), params,
+                        *shared_dev)
         return out, SweepReport(
             n_cells=0, chunk_size=0, n_chunks=0, devices=1, bucketed=False,
             donated=donate)
@@ -422,9 +461,10 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
     else:
         order = np.arange(n_cells)
 
-    executor = _executor(fn, devs, donate)
+    executor = _executor(fn, devs, donate, has_shared)
     chunks, chunk_meta = [], []
-    h2d = uploads = 0
+    shared_dev, shared_bytes = _put_shared(shared, devs)
+    h2d, uploads = shared_bytes, 0
     with warnings.catch_warnings():
         if donate:
             warnings.filterwarnings("ignore", message=_DONATION_MSG.pattern)
@@ -443,7 +483,7 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
                 chunk_params = _take(params, idx)
             h2d += _host_bytes(chunk_params)
             uploads += 1
-            out = _dispatch(executor, chunk_params)
+            out = _dispatch(executor, chunk_params, *shared_dev)
             chunks.append({k: v[:real] for k, v in out.items()})
             chunk_meta.append(real)
             if on_chunk is not None:
@@ -484,7 +524,7 @@ def execute_sweep(fn: Callable[[Any], Dict[str, Any]], params: Any, *,
         lane_iterations=lane_iters,
         active_lane_fraction_predicted=frac_pred,
         sharding="shard_map" if n_dev > 1 else None, h2d_bytes=h2d,
-        param_uploads=uploads)
+        param_uploads=uploads, shared_bytes=shared_bytes)
     return outputs, report
 
 
@@ -497,7 +537,8 @@ def compact_sweep(step: Callable, params: Any, *,
                   iterations_key: str = "iterations",
                   donated: bool = True,
                   max_segments: Optional[int] = None,
-                  quarantine: bool = False):
+                  quarantine: bool = False,
+                  shared: Any = None):
     """Compacting lane scheduler: a dense resident batch of ``lanes`` lanes,
     refilled from a host-side work queue as lanes finish mid-flight.
 
@@ -527,7 +568,11 @@ def compact_sweep(step: Callable, params: Any, *,
     holds every cell in order, the mirror is ``params`` itself, not a
     gathered copy.  ``SweepReport.param_uploads`` counts the copies.
     ``devices`` are the devices the step's lanes are sharded over; empty
-    or one means the default device.
+    or one means the default device.  A plan's sweep-wide ``shared``
+    operand (no cell axis) is put on the device once, replicated, before
+    the first segment, and handed to every call as
+    ``step(lane_params, state, it, fresh, shared)``; refills and a retried
+    segment reuse it (``SweepReport.shared_bytes``).
 
     Because lanes are independent and a retired lane's state/iteration pair
     at its final segment equals the monolithic run's, outputs are
@@ -605,6 +650,7 @@ def compact_sweep(step: Callable, params: Any, *,
         fresh = np.ones(L, bool)
     placement = lanes_sharding(devices) if n_devices > 1 else None
     lane_dev = None            # the mirror's device copy; None once stale
+    shared_dev, shared_bytes = _put_shared(shared, devices)
 
     def dispatch():
         nonlocal h2d, uploads, lane_dev
@@ -614,11 +660,12 @@ def compact_sweep(step: Callable, params: Any, *,
                 uploads += 1
                 lane_dev = jax.device_put(lane_params, placement)
             h2d += _host_bytes(state, it, fresh)
-            return step(lane_dev, state, it, fresh)
+            return step(lane_dev, state, it, fresh, *shared_dev)
 
     outputs: Optional[Dict[str, np.ndarray]] = None
     lane_iters = np.zeros(n_cells, np.int64)
-    segments = refills = retires = executed = retried = h2d = uploads = 0
+    segments = refills = retires = executed = retried = uploads = 0
+    h2d = shared_bytes
     quarantined_cells: list = []
     with warnings.catch_warnings():
         if donated:
@@ -726,7 +773,7 @@ def compact_sweep(step: Callable, params: Any, *,
         quarantined=len(quarantined_cells), retried_segments=retried,
         quarantined_cells=(np.asarray(quarantined_cells, np.int64)
                            if quarantined_cells else None),
-        h2d_bytes=h2d, param_uploads=uploads)
+        h2d_bytes=h2d, param_uploads=uploads, shared_bytes=shared_bytes)
     return outputs, report
 
 

@@ -13,7 +13,8 @@ is a declarative definition:
   * a **statics** object (hashable; shape-defining, trace-specializing) with
     an optional ``use_pallas`` field the driver reads;
   * a **params pytree** whose every leaf carries the cell axis first (the
-    sweep layer's calling convention);
+    sweep layer's calling convention), and optionally one sweep-wide
+    ``shared`` operand with no cell axis (:class:`BatchPlan`);
   * a ``build(params, statics, ops) -> Loop`` function returning the loop's
     initial **state pytree**, its ``cond``/``body`` transition functions, and
     a traced metrics **finalizer** — ``ops`` is a
@@ -143,13 +144,22 @@ def run_one(engine: VecEngine, params: Any, statics: Any) -> Dict[str, Any]:
 
 
 @functools.lru_cache(maxsize=64)
-def batched_sim(engine: VecEngine, statics: Any) -> Callable:
+def batched_sim(engine: VecEngine, statics: Any,
+                shared: bool = False) -> Callable:
     """Batched (vmap) simulator for one static shape, in the sweep layer's
-    single-pytree calling convention — cached so the sweep executor (which
-    jits with buffer donation) reuses one compiled executable per shape.
-    Its ops carry the scope ``<kind>/loop`` in the profiler's trace."""
+    calling convention: ``fn(lane_params)``, or with ``shared``
+    ``fn(lane_params, shared)``, the plan's sweep-wide operand unbatched
+    (``build`` then sees the pair ``(lane, shared)``).  Cached so the
+    sweep executor (which jits with buffer donation) reuses one compiled
+    executable per shape.  Its ops carry the scope ``<kind>/loop`` in the
+    profiler's trace."""
+    def scoped(fn):
+        return jax.named_scope(engine.kind)(jax.named_scope("loop")(fn))
+    if shared:
+        return jax.vmap(scoped(lambda lane, shared_op: run_one(
+            engine, (lane, shared_op), statics)), in_axes=(0, None))
     one = functools.partial(run_one, engine, statics=statics)
-    return jax.vmap(jax.named_scope(engine.kind)(jax.named_scope("loop")(one)))
+    return jax.vmap(scoped(one))
 
 
 # -- compacting-scheduler segment step -----------------------------------------
@@ -168,9 +178,11 @@ def _emit_progress(sink_id, done, j) -> None:
 
 
 @functools.lru_cache(maxsize=64)
-def _segment_sim(engine: VecEngine, statics: Any, budget: int) -> Callable:
+def _segment_sim(engine: VecEngine, statics: Any, budget: int,
+                 shared: bool = False) -> Callable:
     """vmapped segment body: resume/merge, advance ≤ ``budget`` iterations,
-    report termination + finalized outputs.
+    report termination + finalized outputs.  With ``shared`` it takes the
+    plan's sweep-wide operand as a fifth argument, unbatched.
 
     ``use_pallas`` routes the reductions through the next-event kernel,
     as on the monolithic path; outputs stay bit-identical to the
@@ -181,8 +193,9 @@ def _segment_sim(engine: VecEngine, statics: Any, budget: int) -> Callable:
 
     @jax.named_scope(engine.kind)
     @jax.named_scope("segment")
-    def seg_one(params, state, it, fresh):
-        loop = engine.build(params, statics, ops)
+    def seg_one(params, state, it, fresh, *shared_op):
+        cell = (params, *shared_op) if shared_op else params
+        loop = engine.build(cell, statics, ops)
         # A fresh lane adopts its new cell's initial state; a resident lane
         # resumes exactly where the previous segment paused it.  The merge
         # is a leafwise where(), so resuming never re-runs any iteration —
@@ -205,20 +218,22 @@ def _segment_sim(engine: VecEngine, statics: Any, budget: int) -> Callable:
         out.setdefault("iterations", it)
         return state, it, done, j, out
 
-    return jax.vmap(seg_one)
+    return jax.vmap(seg_one, in_axes=(0, 0, 0, 0, None) if shared else 0)
 
 
 @functools.lru_cache(maxsize=64)
 def segment_step(engine: VecEngine, statics: Any, budget: int,
                  devices: tuple, donate: bool = True,
-                 tap: bool = False) -> Callable:
+                 tap: bool = False, shared: bool = False) -> Callable:
     """Compiled segment dispatcher for the compacting scheduler.
 
-    ``step(lane_params, state, it, fresh, sink_id) -> (state, it, done, j,
-    out)`` — :func:`repro.core.sweep.compact_sweep`'s step contract plus a
-    trailing sink id for the retire tap.  Cached per (engine, statics,
-    budget, placement): refills re-enter the same executable, so recompiles
-    happen once per shape, never per refill.  The in-graph retire tap is
+    ``step(lane_params, state, it, fresh, sink_id[, shared]) -> (state,
+    it, done, j, out)`` — :func:`repro.core.sweep.compact_sweep`'s step
+    contract plus a sink id for the retire tap, then, with ``shared``, the
+    plan's sweep-wide operand (replicated, never donated).  Cached per
+    (engine, statics, budget, placement, shared): refills re-enter the
+    same executable, so recompiles happen once per shape, never per
+    refill.  The in-graph retire tap is
     compiled in only when ``tap`` is set (an ordered ``io_callback``
     serializes the device stream — dead weight when no sink is listening).
     Multi-device wraps the vmap in
@@ -227,21 +242,26 @@ def segment_step(engine: VecEngine, statics: Any, budget: int,
     resident batch owns one set of device buffers.
     """
     from jax.experimental import io_callback
-    core = _segment_sim(engine, statics, budget)
+    core = _segment_sim(engine, statics, budget, shared)
     donate_argnums = (1, 2) if donate else ()
     if len(devices) > 1:
+        from jax.sharding import PartitionSpec
         sh = lanes_sharding(devices)
-        # check_vma=False: lanes are independent, nothing is replicated.
-        sharded = jax.shard_map(core, mesh=sh.mesh, in_specs=(sh.spec,) * 4,
-                                out_specs=sh.spec, check_vma=False)
+        # check_vma=False: lanes are independent; the shared operand is
+        # only read.
+        sharded = jax.shard_map(
+            core, mesh=sh.mesh,
+            in_specs=(sh.spec,) * 4 + ((PartitionSpec(),) if shared else ()),
+            out_specs=sh.spec, check_vma=False)
 
-        def stepped(lane_params, state, it, fresh, sink_id):
+        def stepped(lane_params, state, it, fresh, sink_id, *shared_op):
             del sink_id                # retire tap is single-device only
-            return sharded(lane_params, state, it, fresh)
+            return sharded(lane_params, state, it, fresh, *shared_op)
         return jax.jit(stepped, donate_argnums=donate_argnums)
 
-    def stepped(lane_params, state, it, fresh, sink_id):
-        state, it, done, j, out = core(lane_params, state, it, fresh)
+    def stepped(lane_params, state, it, fresh, sink_id, *shared_op):
+        state, it, done, j, out = core(lane_params, state, it, fresh,
+                                       *shared_op)
         if tap:
             # In-graph retire tap: streams (done mask, per-lane segment
             # iters) to the registered host sink as the device stream
@@ -260,23 +280,44 @@ def segment_step(engine: VecEngine, statics: Any, budget: int,
     return jax.jit(stepped, donate_argnums=donate_argnums)
 
 
-def state_prototype(engine: VecEngine, statics: Any, params: Any):
-    """Shape/dtype pytree of one cell's loop state — via ``eval_shape``, so
-    no device computation runs.  Callers must be under the same x64 regime
-    as the dispatch (``run_plan`` enters it)."""
+def _shapes(tree, lead: int = 0):
+    """Shape/dtype structs of ``tree``'s leaves, less ``lead`` leading
+    axes; read from the leaves' metadata, so a device leaf is not copied."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a)[lead:], a.dtype), tree)
+
+
+def state_prototype(engine: VecEngine, statics: Any, params: Any,
+                    shared: Any = None):
+    """Shape/dtype pytree of one cell's loop state — via ``eval_shape`` on
+    one lane's shapes (and the plan's ``shared`` operand, when it has
+    one), so no device computation runs.  Callers must be under the same
+    x64 regime as the dispatch (``run_plan`` enters it)."""
     ops = MaskedOps(bool(getattr(statics, "use_pallas", False)))
-    one = jax.tree_util.tree_map(lambda a: np.asarray(a)[0], params)
+    one = _shapes(params, lead=1)
+    if shared is not None:
+        one = (one, _shapes(shared))
     return jax.eval_shape(
         lambda p: engine.build(p, statics, ops).init, one)
 
 
 class BatchPlan(NamedTuple):
-    """What ``prepare`` hands the driver: data + schedule for one batch."""
+    """What ``prepare`` hands :func:`run_plan`: data + schedule for one
+    batch.
+
+    ``params`` are the lane params: every leaf carries the cell axis
+    first, and the sweep layer gathers, pads and refills them per lane.
+    ``shared`` (optional) is one sweep-wide operand with no cell axis: put
+    on the device once a sweep, replicated over the devices, handed to
+    every executable call beside the lane params and never gathered,
+    refilled, donated or sent again.  ``build`` then receives the pair
+    ``(lane, shared)`` for its ``params``."""
 
     params: Any                               # batched pytree, cell axis first
     statics: Any                              # hashable; may carry use_pallas
     predicted_cost: Optional[Any] = None      # per-cell loop-length estimate
     finalize: Optional[Callable[[Dict[str, Any]], Any]] = None  # host-side
+    shared: Any = None                        # sweep-wide, no cell axis
 
 
 class Done(NamedTuple):
@@ -347,19 +388,20 @@ def run_compact(engine: VecEngine, plan: BatchPlan, *, chunk_size=None,
         sid = next(_progress_ids)
         _PROGRESS_SINKS[sid] = progress
     step5 = segment_step(engine, statics, budget, devs, donate,
-                         tap=sid != 0)
+                         tap=sid != 0, shared=plan.shared is not None)
     sid_arr = np.int32(sid)
 
-    def step(lane_params, state, it, fresh):
-        return step5(lane_params, state, it, fresh, sid_arr)
+    def step(lane_params, state, it, fresh, *shared):
+        return step5(lane_params, state, it, fresh, sid_arr, *shared)
 
     with span("sweep.stage"):
-        prototype = state_prototype(engine, statics, params)
+        prototype = state_prototype(engine, statics, params, plan.shared)
     try:
         return compact_sweep(
             step, params, lanes=lanes, state_prototype=prototype,
             devices=devs, predicted_cost=plan.predicted_cost,
-            on_chunk=on_chunk, donated=donate, quarantine=quarantine)
+            on_chunk=on_chunk, donated=donate, quarantine=quarantine,
+            shared=plan.shared)
     finally:
         if sid:
             jax.effects_barrier()       # drain the ordered tap before unhook
@@ -396,10 +438,11 @@ def run_plan(engine: VecEngine, plan, *, chunk_size=None, devices=None,
                     quarantine=quarantine)
             else:
                 out, report = execute_sweep(
-                    batched_sim(engine, plan.statics), plan.params,
+                    batched_sim(engine, plan.statics,
+                                plan.shared is not None), plan.params,
                     chunk_size=chunk_size, devices=devices, donate=donate,
                     predicted_cost=plan.predicted_cost,
-                    on_chunk=on_chunk)
+                    on_chunk=on_chunk, shared=plan.shared)
         if plan.finalize is not None:
             with span("sweep.finalize"):
                 out = plan.finalize(out)

@@ -51,34 +51,42 @@ class _Statics(NamedTuple):
 
 
 class _Params(NamedTuple):
-    """The routing tables the compiled loop reads, packed row-per-request
-    (cell axis first); :func:`summarize` reads the rest from the batch's
-    request tables on the host.
+    """A lane's index into the sweep's request tables (cell axis first):
+    the request stream its cell draws and its
+    :func:`~repro.core.llmserve.packed_layout` columns up to ``kv_need``.
 
-    Packing everything the body needs for request ``it`` into one
-    ``[J, K]`` tensor turns the loop's per-iteration table access into a
-    *single* dynamic slice (instead of eight separate gathers across
-    eight operands) — measurably faster on CPU where per-op dispatch
-    dominates this small body, and bit-preserving: the doubles are the
-    same, only their storage layout changes.  Layout per row: the
-    :func:`~repro.core.llmserve.packed_layout` fields up to ``kv_need``."""
-    packed: jnp.ndarray       # [J, K]    f64 (or its i64 bits, kv_need an i64)
+    The tables themselves are the plan's sweep-wide ``shared`` operand
+    (:func:`_tables`), put on the device once a sweep; each lane gathers
+    its packed ``[J, K]`` rows from them before its loop (:func:`_rows`).
+    Packing everything the body needs for request ``it`` into one row
+    turns the loop's per-iteration table access into a *single* dynamic
+    slice (instead of eight separate gathers across eight operands), and
+    is bit-preserving: the doubles are the same, only their storage
+    layout changes.  :func:`summarize` reads the host tables in place."""
+    stream: jnp.ndarray       # []   i32 the cell's request stream
+    cols: jnp.ndarray         # [K]  i32 its columns in that stream's table
 
 
-def _pack(cells: LLMServeBatch, exact_bits: bool) -> np.ndarray:
-    """The whole batch's ``[B, J, K]`` packed table, each cell's rows
-    gathered from its stream's request table straight into the buffer
-    that becomes the lane params.  On the bit route the doubles travel
-    as their int64 patterns (a view, not a copy) and ``kv_need`` as the
-    integer it is."""
-    k = cells.layout["kv_need"].stop
-    packed = np.empty((len(cells), cells.table.shape[1], k), np.float64)
-    for i in range(len(cells)):
-        cells.columns(i, k, out=packed[i])
+def _tables(cells: LLMServeBatch, exact_bits: bool) -> np.ndarray:
+    """The batch's request tables, one ``[C, J]`` per stream (``[U, C,
+    J]``), so that a lane's packed rows are whole rows of it.  On the bit
+    route the doubles travel as their int64 patterns and the ``kv_need``
+    column as the integer it is."""
+    tables = np.ascontiguousarray(cells.table.transpose(0, 2, 1))
     if exact_bits:
-        packed = f64bits.bits(packed)
-        packed[..., -1] = cells.requests["kv_need"][cells.stream]
-    return packed
+        tables = f64bits.bits(tables)
+        kv_col = cells.cols[0, cells.layout["kv_need"].start]
+        tables[:, kv_col] = cells.requests["kv_need"]
+    return tables
+
+
+def _rows(lane: _Params, tables: jnp.ndarray) -> jnp.ndarray:
+    """A lane's packed ``[J, K]`` table: its ``K`` columns of its stream's
+    table, gathered as whole rows of ``J`` entries (one embedding-style
+    gather), then transposed once."""
+    n_cols = tables.shape[1]
+    flat = tables.reshape(-1, tables.shape[2])            # [U·C, J]
+    return flat[lane.stream * n_cols + lane.cols].T
 
 
 class _Carry(NamedTuple):
@@ -91,7 +99,9 @@ class _Carry(NamedTuple):
 
 def _llmserve_build(cell, s: _Statics, ops) -> Loop:
     """One request routed per iteration: the vectorized form of
-    :func:`repro.core.llmserve.route_request`, all pipelines at once."""
+    :func:`repro.core.llmserve.route_request`, all pipelines at once.
+    ``cell`` is the pair ``(lane params, shared request tables)``."""
+    packed = _rows(*cell)
     pipes = jnp.arange(s.n_pipelines)
     P, S = s.n_pipelines, s.n_stages
     f = packed_layout(P, S)
@@ -106,7 +116,7 @@ def _llmserve_build(cell, s: _Statics, ops) -> Loop:
     def body(c: _Carry, it) -> _Carry:
         # One dynamic slice fetches everything request `it` needs; the
         # splits below are static (fused into the gather by XLA).
-        row = cell.packed[it]                         # [K]
+        row = packed[it]                              # [K]
         submit = row[0]
         svc = row[f["svc"]].reshape(P, S)
         hop = row[f["hop"]].reshape(P, S)
@@ -142,7 +152,7 @@ def _llmserve_build(cell, s: _Statics, ops) -> Loop:
             ttft=c.ttft.at[it].set(jnp.where(
                 ok, add(start_last[pick], first_extra[pick]), inf)))
 
-    dtype = cell.packed.dtype
+    dtype = packed.dtype
     return Loop(
         init=_Carry(free=jnp.zeros((P, S), dtype),
                     kv_used=jnp.zeros((P, S), jnp.int64),
@@ -190,17 +200,19 @@ def _prepare_llmserve(*, use_pallas: bool, seeds=(0,), n_machines: int = 6,
             int(n_machines), faulted=fault_plan is not None
             or math.isfinite(timeout_s)))
     exact_bits = not f64bits.native()
-    with span("sweep.prepare.pack"):
-        packed = _pack(cells, exact_bits)
     n_pipes, n_st = cells.placement.shape[1:]
+    k = cells.layout["kv_need"].stop
+    lanes = _Params(stream=cells.stream.astype(np.int32),
+                    cols=cells.cols[:, :k].astype(np.int32))
     # Every lane routes exactly n_requests requests (an injected workload
     # sets its own): nothing to bucket.
-    return BatchPlan(_Params(packed=packed),
-                     _Statics(packed.shape[1], int(n_pipes), int(n_st),
+    return BatchPlan(lanes,
+                     _Statics(cells.table.shape[1], int(n_pipes), int(n_st),
                               bool(use_pallas), timeout=cells.timeout_s,
                               f64_bits=exact_bits),
                      finalize=lambda out: summarize(
-                         _doubles(out) if exact_bits else out, cells))
+                         _doubles(out) if exact_bits else out, cells),
+                     shared=_tables(cells, exact_bits))
 
 
 def _doubles(out):

@@ -207,7 +207,8 @@ LLM = dict(seeds=np.arange(8), n_requests=40)
 def test_identity_batch_uploads_params_once(monkeypatch):
     """Every cell resident in order (no predicted cost, lanes = cells): the
     caller's params go to the device as they are — no gathered copy — once,
-    and every later segment reuses the device copy."""
+    and every later segment reuses the device copy, as it reuses the plan's
+    shared request tables, put there once before them."""
     import jax
 
     from repro.core import vec_engine
@@ -227,8 +228,10 @@ def test_identity_batch_uploads_params_once(monkeypatch):
     out, rep = run_sweep("llmserve_batch", LLM, config=SweepConfig(
         compact=True, chunk_size=8, segment_iters=16))
     assert rep.segments > 1 and rep.refills == 0
-    assert rep.param_uploads == 1 and len(put) == 1
-    for sent, given in zip(jax.tree_util.tree_leaves(put[0]),
+    assert rep.param_uploads == 1 and len(put) == 2
+    tables, lanes = put
+    assert rep.shared_bytes == np.asarray(tables).nbytes > 0
+    for sent, given in zip(jax.tree_util.tree_leaves(lanes),
                            jax.tree_util.tree_leaves(handed[0])):
         assert np.shares_memory(sent, np.asarray(given))
     for k in mono:

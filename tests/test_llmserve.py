@@ -10,6 +10,7 @@ serving-metric invariants of :func:`repro.core.llmserve.summarize`.
 
 import math
 
+import jax
 import numpy as np
 import pytest
 
@@ -24,7 +25,8 @@ from repro.core.llmserve import (ACT_BYTES_PER_TOKEN, FIRST_TOKEN_BYTES,
                                  llmserve_workload, machine_regions)
 from repro.core.network import InterDCTopology
 from repro.core.sweep import SweepConfig
-from repro.core.vec_llmserve import _pack
+from repro.core.vec_engine import x64
+from repro.core.vec_llmserve import _prepare_llmserve, _rows
 
 
 def _run(backend="vec", **kw):
@@ -231,11 +233,24 @@ def _same(a, b) -> bool:
         a.tobytes() == b.tobytes()
 
 
+def _gathered_rows(kw, exact_bits: bool, monkeypatch) -> np.ndarray:
+    """The packed ``[B, J, K]`` rows the lanes' loops read on one route:
+    the plan's lane params and shared request tables, gathered on the
+    device as the engine gathers them."""
+    monkeypatch.setattr(f64bits, "native", lambda: not exact_bits)
+    plan = _prepare_llmserve(use_pallas=False, **kw)
+    assert plan.statics.f64_bits == exact_bits
+    with x64():
+        return np.asarray(jax.jit(jax.vmap(_rows, in_axes=(0, None)))(
+            plan.params, plan.shared))
+
+
 @pytest.mark.parametrize("case", sorted(EQUIV))
-def test_factored_build_matches_per_cell_construction(case):
-    """Every packed table entry and every ``LLMServeCell`` field equals a
-    plain construction of that cell alone, byte for byte, on both packed
-    routes; the batch draws one request stream per distinct input."""
+def test_factored_build_matches_per_cell_construction(case, monkeypatch):
+    """Every packed row a lane gathers on the device and every
+    ``LLMServeCell`` field equals a plain construction of that cell alone,
+    byte for byte, on both routes; the batch draws one request stream per
+    distinct input."""
     kw, n_streams = EQUIV[case]
     kw = dict(n_requests=30, decode_tokens=(16, 90_000), **kw)
     batch, b = build_cells(**kw)
@@ -272,8 +287,8 @@ def test_factored_build_matches_per_cell_construction(case):
         for k, v in fx.items():
             assert _same(getattr(got.fx, k), v), (i, k)
     packed = np.stack(rows)
-    assert _same(_pack(batch, False), packed)
-    bits = _pack(batch, True)
+    assert _same(_gathered_rows(kw, False, monkeypatch), packed)
+    bits = _gathered_rows(kw, True, monkeypatch)
     want_bits = f64bits.bits(packed.copy())
     want_bits[..., -1] = packed[..., -1].astype(np.int64)
     assert bits.dtype == np.int64 and _same(bits, want_bits)

@@ -23,9 +23,12 @@ POWER = dict(seeds=np.arange(8), n_hosts=6, n_vms=20, n_samples=24,
 CONFIGS = {"compact": SweepConfig(compact=True, chunk_size=4,
                                   segment_iters=16),
            "chunked": SweepConfig(chunk_size=4)}
-NAMES = {"sweep", "sweep.validate", "sweep.prepare", "sweep.prepare.build",
-         "sweep.prepare.pack", "sweep.stage", "sweep.dispatch", "sweep.wait",
-         "sweep.finalize"}
+NAMES = {"sweep", "sweep.validate", "sweep.prepare", "sweep.stage",
+         "sweep.dispatch", "sweep.wait", "sweep.finalize"}
+# The engine's own host build under ``sweep.prepare``: llmserve's request
+# tables go to the device as they are built; power packs its lane params.
+LLMSERVE_PREPARE = ("sweep.prepare.build",)
+POWER_PREPARE = ("sweep.prepare.build", "sweep.prepare.pack")
 
 
 def _traced(directory, config, kind=KIND, params=PARAMS):
@@ -51,27 +54,28 @@ def traced_power(request, tmp_path_factory):
     return request.param, res, found
 
 
-def _assert_nested(path, found):
+def _assert_nested(path, found, prepare):
     sweeps = [p for p in found if p[0] == "sweep"]
     assert len(sweeps) == 1
     _, lo, hi, _ = sweeps[0]
     assert all(lo <= s and e <= hi for _, s, e, _ in found)
-    want = NAMES | ({"sweep.retire"} if path == "compact" else set())
+    want = NAMES | set(prepare) | (
+        {"sweep.retire"} if path == "compact" else set())
     assert {p[0] for p in found} == want
     (_, plo, phi, _), = [p for p in found if p[0] == "sweep.prepare"]
-    for child in ("sweep.prepare.build", "sweep.prepare.pack"):
+    for child in prepare:
         (_, s, e, _), = [p for p in found if p[0] == child]
         assert plo <= s and e <= phi
 
 
 def test_every_span_nests_under_one_sweep(traced):
     path, _, found = traced
-    _assert_nested(path, found)
+    _assert_nested(path, found, LLMSERVE_PREPARE)
 
 
 def test_power_sweep_spans_nest_under_one_sweep(traced_power):
     path, _, found = traced_power
-    _assert_nested(path, found)
+    _assert_nested(path, found, POWER_PREPARE)
 
 
 def test_sweep_span_carries_the_report(traced):
@@ -96,13 +100,17 @@ def test_sweep_span_carries_the_report(traced):
 
 def _h2d_from_shapes(engine, plan, path, rep, lanes=4):
     """The bytes a sweep of ``plan`` on ``lanes`` lanes hands the device, as
-    the shapes of its lane params and loop state give them."""
+    the shapes of its lane params and loop state give them, and its shared
+    operand's, once a sweep."""
     lane_params = sum(lanes * leaf[0].nbytes
                       for leaf in jax.tree_util.tree_leaves(plan.params))
+    shared = sum(leaf.nbytes
+                 for leaf in jax.tree_util.tree_leaves(plan.shared))
+    assert rep.shared_bytes == shared
     if path == "compact":
         with vec_engine.x64():
             proto = vec_engine.state_prototype(engine, plan.statics,
-                                               plan.params)
+                                               plan.params, plan.shared)
         state = sum(lanes * int(np.prod(sd.shape)) * sd.dtype.itemsize
                     for sd in jax.tree_util.tree_leaves(proto))
         # The lane params go to the device once, and again for each segment
@@ -116,7 +124,7 @@ def _h2d_from_shapes(engine, plan, path, rep, lanes=4):
     else:
         assert rep.param_uploads == rep.n_chunks
         want = rep.n_chunks * lane_params
-    return want
+    return want + shared
 
 
 def test_h2d_bytes_from_shapes(traced):
@@ -127,6 +135,7 @@ def test_h2d_bytes_from_shapes(traced):
     assert rep.h2d_bytes == want
     (_, _, _, stats), = [p for p in found if p[0] == "sweep"]
     assert stats["h2d_bytes"] == want
+    assert stats["shared_bytes"] == rep.shared_bytes == plan.shared.nbytes
 
 
 def test_power_sweep_counters_from_shapes(traced_power):
@@ -140,6 +149,7 @@ def test_power_sweep_counters_from_shapes(traced_power):
     (_, _, _, stats), = [p for p in found if p[0] == "sweep"]
     assert stats["h2d_bytes"] == want
     assert stats["param_uploads"] == rep.param_uploads
+    assert stats["shared_bytes"] == rep.shared_bytes == 0
     # 8 cells, 24 intervals: 2 chunks of 4 lanes, or 4 lanes of 16-interval
     # segments, each cell taking 2.
     assert stats["dispatches"] == rep.dispatches == (
@@ -209,16 +219,17 @@ def test_device_ops_carry_the_engine_scope():
     plan = _prepare_llmserve(use_pallas=False, seeds=np.arange(2),
                              n_requests=8)
     with vec_engine.x64():
-        loop = jax.jit(vec_engine.batched_sim(LLMSERVE_ENGINE, plan.statics))
-        hlo = loop.lower(plan.params).compile().as_text()
+        loop = jax.jit(vec_engine.batched_sim(LLMSERVE_ENGINE, plan.statics,
+                                              shared=True))
+        hlo = loop.lower(plan.params, plan.shared).compile().as_text()
         seg = jax.jit(vec_engine._segment_sim(LLMSERVE_ENGINE, plan.statics,
-                                              4))
+                                              4, shared=True))
         proto = vec_engine.state_prototype(LLMSERVE_ENGINE, plan.statics,
-                                           plan.params)
+                                           plan.params, plan.shared)
         state = jax.tree_util.tree_map(
             lambda sd: np.zeros((2,) + sd.shape, sd.dtype), proto)
         seg_hlo = seg.lower(plan.params, state, np.zeros(2, np.int32),
-                            np.ones(2, bool)).compile().as_text()
+                            np.ones(2, bool), plan.shared).compile().as_text()
     assert re.search(r'op_name="[^"]*llmserve_batch\)?/loop/while', hlo)
     assert re.search(r'op_name="[^"]*llmserve_batch\)?/segment/while',
                      seg_hlo)

@@ -47,8 +47,14 @@ def _shapes(tree, sharding):
 
 
 def _compile_loop(engine, plan, sharding):
-    return jax.jit(batched_sim(engine, plan.statics)).lower(
-        _shapes(plan.params, sharding)).compile()
+    """The monolithic loop of ``plan``, with its shared operand beside the
+    lane params where it has one (llmserve's request tables, which each
+    lane gathers its rows from before the loop)."""
+    args = (plan.params,) if plan.shared is None else (plan.params,
+                                                       plan.shared)
+    return jax.jit(batched_sim(engine, plan.statics,
+                               plan.shared is not None)).lower(
+        *_shapes(args, sharding)).compile()
 
 
 def test_power_loop_compiles_at_planetlab_width(one_chip):
@@ -73,7 +79,7 @@ def test_llmserve_loop_compiles_at_bench_width(one_chip, monkeypatch):
             use_pallas=False, seeds=np.arange(256), placement=placement,
             n_machines=24, n_regions=3, n_stages=2, n_requests=512,
             decode_tokens=(16, 90_000))
-        assert plan.statics.f64_bits
+        assert plan.statics.f64_bits and plan.shared.dtype == np.int64
         _compile_loop(LLMSERVE_ENGINE, plan, one_chip)
 
 
